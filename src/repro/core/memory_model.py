@@ -35,7 +35,22 @@ from typing import Optional, Tuple
 from ..models import remat as remat_lib
 from ..models.config import ModelConfig
 
+#: the planning budget off the chip (CPU tests, dry-run compiles); on a
+#: chip the device's own limit is used (:func:`device_bytes_limit`)
 V5E_HBM_BYTES = 16 * 1024 ** 3
+
+
+def device_bytes_limit(device) -> int:
+    """The memory the device itself reports it can allocate
+    (``memory_stats()["bytes_limit"]``). A device that reports none
+    raises: planning against a guess could admit a micro-batch that
+    runs out of memory."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        raise RuntimeError(
+            f"{device.device_kind} reports no memory limit; pass "
+            "--hbm-budget-gb to plan against an explicit budget")
+    return int(stats["bytes_limit"])
 
 # lattice order == the planner's escalation order (cheapest recompute first)
 POLICY_ORDER = remat_lib.POLICIES

@@ -67,7 +67,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..launch import mesh as mesh_lib
@@ -483,7 +483,7 @@ class PipelinedExecutor:
             local, mesh=self.mesh,
             in_specs=(shared_specs, staged_specs, split_specs),
             out_specs=(shared_specs, staged_specs, P(), P()),
-            check_rep=False)(shared, staged, split)
+            check_vma=False)(shared, staged, split)
         grads = self.staged.combine(g_sh, g_st)
         return grads, loss, metrics
 

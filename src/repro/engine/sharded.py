@@ -51,7 +51,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..launch import mesh as mesh_lib
@@ -294,7 +294,7 @@ class ShardedExecutor:
             return shard_map(local_step, mesh=self.mesh,
                              in_specs=(P(), P(), specs),
                              out_specs=(P(), P(), P()),
-                             check_rep=False)(params, opt_state, micro_batches)
+                             check_vma=False)(params, opt_state, micro_batches)
 
         return train_step
 
@@ -374,7 +374,7 @@ class ShardedExecutor:
                 return shard_map(local, mesh=self.mesh,
                                  in_specs=(P(), specs),
                                  out_specs=(P(), P()),
-                                 check_rep=False)(p, mb)
+                                 check_vma=False)(p, mb)
             self._grads_jit = jax.jit(run)
         return self._grads_jit(params, micro_batches)
 
@@ -436,19 +436,19 @@ class ShardedExecutor:
             return shard_map(local_micro, mesh=self.mesh,
                              in_specs=(P(), carry_spec, micro_specs(mb)),
                              out_specs=carry_spec,
-                             check_rep=False)(params, carry, mb)
+                             check_vma=False)(params, carry, mb)
 
         def wrap_update(params, opt_state, carry, n_s):
             return shard_map(lambda p, s, c: local_update(p, s, c, n_s),
                              mesh=self.mesh,
                              in_specs=(P(), P(), carry_spec),
                              out_specs=(P(), P(), P()),
-                             check_rep=False)(params, opt_state, carry)
+                             check_vma=False)(params, opt_state, carry)
 
         def wrap_grads(carry):
             return shard_map(local_grads, mesh=self.mesh,
                              in_specs=(carry_spec,), out_specs=(P(), P()),
-                             check_rep=False)(carry)
+                             check_vma=False)(carry)
 
         self._stream_micro = jax.jit(
             wrap_micro, donate_argnums=(1,) if self._donate else ())

@@ -1,4 +1,7 @@
 import os
+# a CPU-simulated compile by design: 512 host devices, never the TPU (on a
+# machine with a chip, the chip belongs to one process at a time)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
@@ -102,7 +105,7 @@ def _compile(bundle, mesh, fsdp_over_pod: bool = False, fsdp: bool = True,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     else:
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 bundle.fn,
                 in_shardings=tuple(sharding.named(s, mesh)

@@ -7,10 +7,15 @@ all-reduce, which MBS amortizes to once per mini-batch.
 
 ``make_production_mesh`` is a function (never a module-level constant) so
 importing this module does not touch jax device state.
+
+Every mesh is built with Auto axes: the model's ``shard_hint`` constraints
+and the GSPMD step rely on the compiler propagating shardings, which
+``jax.make_mesh``'s default Explicit axes forbid.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
@@ -20,14 +25,18 @@ POD_AXIS = "pod"
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = (POD_AXIS, DATA_AXIS, MODEL_AXIS) if multi_pod else (DATA_AXIS, MODEL_AXIS)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
     """Small mesh over however many (host) devices exist — used by tests."""
     if pod:
-        return jax.make_mesh((pod, data, model), (POD_AXIS, DATA_AXIS, MODEL_AXIS))
-    return jax.make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
+        return _auto_mesh((pod, data, model), (POD_AXIS, DATA_AXIS, MODEL_AXIS))
+    return _auto_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
 
 
 def parse_mesh_spec(spec: str, device_count: int | None = None):

@@ -25,7 +25,7 @@ from .. import configs
 from ..core.streaming import prefetch_iterator
 from ..engine import serving
 from ..models import transformer
-from . import mesh as mesh_lib
+from . import compile_cache, mesh as mesh_lib
 
 
 def _int_list(s: str):
@@ -64,6 +64,7 @@ def main(argv=None):
     ap.add_argument("--json", default=None,
                     help="also write the full report to this path")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = configs.get_reduced(args.arch) if args.reduced else configs.get(args.arch)
     try:
@@ -78,7 +79,7 @@ def main(argv=None):
     budget = int(args.budget * 2**30)
     mesh = mesh_lib.make_host_mesh(data=len(jax.devices()), model=1)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         plan = serving.plan_serve(
             cfg, budget_bytes=budget, max_len=args.max_len,
             max_slots=args.slots, prefill_micro=args.prefill_micro,

@@ -32,15 +32,18 @@ DESIGN.md §Fault tolerance).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 
 from .. import configs, engine, optim
+from ..core import memory_model
 from ..data import LMDataset
 from ..models import encdec, transformer
-from . import mesh as mesh_lib, sharding, steps
+from . import compile_cache, mesh as mesh_lib, sharding, steps
 
 
 def build_mesh(args):
@@ -60,6 +63,19 @@ def default_optimizer(args) -> optim.Optimizer:
     return optim.sgd(args.lr, momentum=0.9, weight_decay=5e-4)
 
 
+def budget_bytes(args) -> Optional[int]:
+    """Per-device planning budget: ``--hbm-budget-gb`` when given; on a
+    TPU the limit the device itself reports; elsewhere ``None``, which
+    plans against ``memory_model.V5E_HBM_BYTES`` (CPU and dry-run
+    planning)."""
+    if args.hbm_budget_gb:
+        return int(args.hbm_budget_gb * 1024 ** 3)
+    device = jax.devices()[0]
+    if device.platform == "tpu":
+        return memory_model.device_bytes_limit(device)
+    return None
+
+
 def build_plan(cfg, args, optimizer=None, mesh=None) -> engine.MBSPlan:
     """The launcher's batch geometry: pinned N_Sμ when given, else the
     memory model picks the micro-batch size (paper §4.3.2, computed) —
@@ -76,13 +92,11 @@ def build_plan(cfg, args, optimizer=None, mesh=None) -> engine.MBSPlan:
     and the params discount follows the real executor — the host-mesh
     ``ShardedExecutor`` replicates params (``fsdp_params=False``), the
     production GSPMD path FSDP-shards them."""
-    budget = (int(args.hbm_budget_gb * 1024 ** 3)
-              if args.hbm_budget_gb else None)
     dtype_bytes = 4 if args.dtype == "float32" else 2
     optimizer = optimizer or default_optimizer(args)
     return engine.plan_mbs(
         args.mini_batch, num_microbatches=args.microbatches,
-        model_cfg=cfg, seq_len=args.seq, budget_bytes=budget,
+        model_cfg=cfg, seq_len=args.seq, budget_bytes=budget_bytes(args),
         normalization=args.normalization,
         act_bytes=dtype_bytes, remat=not args.reduced,
         remat_policy=getattr(args, "remat_policy", None),
@@ -95,7 +109,8 @@ def build_plan(cfg, args, optimizer=None, mesh=None) -> engine.MBSPlan:
         **optim.memory_model_kw(optimizer, fused=args.executor == "flat"))
 
 
-def build_executor(cfg, plan, args, optimizer=None, mesh=None, guard=False):
+def build_executor(cfg, plan, args, optimizer=None, mesh=None, guard=False,
+                   interpret=None):
     """The step path used by main() — also exercised directly by the
     end-to-end ragged-tail test. The loss compiles under the plan's
     chosen remat policy, so the step matches what the planner admitted.
@@ -108,7 +123,8 @@ def build_executor(cfg, plan, args, optimizer=None, mesh=None, guard=False):
     (``--fsdp`` additionally shards params over the data axis with
     just-in-time gathers). ``guard=True`` (the supervised mode) adds the
     on-device finite-check to the update, surfacing a ``nonfinite``
-    metric."""
+    metric. ``interpret`` reaches the Pallas kernels of the fused and
+    flat strategies (``None``: interpret off-TPU only)."""
     dtype = jnp.float32 if args.dtype == "float32" else jnp.bfloat16
     opt = optimizer or default_optimizer(args)
     if mesh is not None and mesh_lib.axis_size(mesh, mesh_lib.MODEL_AXIS) > 1:
@@ -121,24 +137,29 @@ def build_executor(cfg, plan, args, optimizer=None, mesh=None, guard=False):
                                  remat_policy=plan.remat_policy)
     if mesh is not None and mesh_lib.data_parallel_size(mesh) > 1:
         return engine.ShardedExecutor(loss_fn, opt, plan, mesh=mesh,
-                                      inner=args.executor, guard=guard), opt
+                                      inner=args.executor, guard=guard,
+                                      interpret=interpret), opt
+    kw = {} if args.executor == "streaming" else {"interpret": interpret}
     return engine.get_executor(args.executor)(loss_fn, opt, plan,
-                                              guard=guard), opt
+                                              guard=guard, **kw), opt
 
 
-def make_build(cfg, args, ds, mesh, host_dp, opt):
+def make_build(cfg, args, ds, mesh, host_dp, opt, interpret=None):
     """``plan -> (step_fn, pipeline)``: one factory for all three runtime
     shapes (host-DP sharded, single-device streaming, GSPMD compiled).
     ``main()`` calls it once for the plain ``Trainer``; the Supervisor
     keeps it as the rebuild hook its OOM path re-invokes after degrading
     the plan — everything plan-dependent (executor, jit, pipeline split
-    geometry) is reconstructed from scratch for the new plan."""
+    geometry) is reconstructed from scratch for the new plan. The GSPMD
+    step also carries ``step.lower(params, opt_state, batch)``, the
+    lowering of the very jit it dispatches (for ``memory_analysis`` and
+    the compiled HLO)."""
     guard = args.supervise
 
     def build(plan):
         executor, _ = build_executor(cfg, plan, args, optimizer=opt,
                                      mesh=mesh if host_dp else None,
-                                     guard=guard)
+                                     guard=guard, interpret=interpret)
         if host_dp:
             # data-parallel host mesh (engine Layer 6): per-device
             # accumulation of local_micro samples, ONE deferred gradient
@@ -163,10 +184,15 @@ def make_build(cfg, args, ds, mesh, host_dp, opt):
         def step(params, opt_state, batch):
             # tracing is lazy (first call) and the step body resolves
             # PartitionSpecs against the ambient mesh — keep it active at
-            # dispatch like the pre-factory `with mesh:` block did
-            with mesh:
+            # dispatch
+            with jax.set_mesh(mesh):
                 return jitted(params, opt_state, batch)
 
+        def lower(params, opt_state, batch):
+            with jax.set_mesh(mesh):
+                return jitted.lower(params, opt_state, batch)
+
+        step.lower = lower
         pipeline = engine.Pipeline(ds, plan, prefetch=args.prefetch,
                                    mesh=mesh)
         return step, pipeline
@@ -178,11 +204,10 @@ def make_plan_ctx(cfg, args, mesh, optimizer):
     """The Supervisor's planning context: everything ``build_plan`` knows,
     so an OOM re-plan goes through the same ``plan_mbs`` the launcher used
     — and the observed failure lands in the same tuning-cache key."""
-    budget = (int(args.hbm_budget_gb * 1024 ** 3)
-              if args.hbm_budget_gb else None)
     dtype_bytes = 4 if args.dtype == "float32" else 2
     return dict(
-        model_cfg=cfg, seq_len=args.seq, budget_bytes=budget, mesh=mesh,
+        model_cfg=cfg, seq_len=args.seq, budget_bytes=budget_bytes(args),
+        mesh=mesh,
         executor=args.executor, tuning_cache=args.tuning_cache,
         mm_kw=dict(act_bytes=dtype_bytes, remat=not args.reduced,
                    fsdp_params=args.mesh == "production",
@@ -241,7 +266,9 @@ def run_supervised(supervisor, params, opt_state, args):
     return params, opt_state, last
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """The launcher's command line (``argv=None`` reads ``sys.argv``),
+    validated at parse time."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.ARCHS)
     ap.add_argument("--reduced", action="store_true")
@@ -315,13 +342,15 @@ def main():
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--dtype", choices=["float32", "bfloat16"],
                     default="float32")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    stages = 1
     if args.mesh not in ("host", "production"):
         try:  # validate the DATA:MODEL spec at parse time — fail fast
-            mesh_lib.parse_mesh_spec(args.mesh)
+            stages = mesh_lib.parse_mesh_spec(args.mesh)[1]
         except ValueError as e:
             ap.error(str(e))
-    if args.executor == "streaming" and (args.mesh != "host" or args.multi_pod):
+    if args.executor == "streaming" and (
+            args.mesh == "production" or args.multi_pod or stages > 1):
         # fail fast with the actual contract (not a silent warn-and-ignore):
         # streaming composes with data-parallel HOST meshes through the
         # ShardedExecutor; TP/FSDP production meshes need a compiled
@@ -335,14 +364,24 @@ def main():
                  "'DATA:MODEL' mesh spec with MODEL > 1")
     if args.resume and not args.ckpt_dir:
         ap.error("--resume needs --ckpt-dir")
+    return args
 
-    if args.tuning_cache:
-        # one cache serves both halves: the planner's memory correction
-        # (threaded through build_plan) and the kernels' tuned launch
-        # blocks (resolved through the process-wide active cache)
-        engine.set_cache_path(args.tuning_cache)
 
-    cfg = configs.get_reduced(args.arch) if args.reduced else configs.get(args.arch)
+@dataclasses.dataclass
+class Setup:
+    """Everything ``main`` builds before the step loop."""
+    mesh: Any
+    opt: optim.Optimizer
+    plan: engine.MBSPlan
+    params: Any
+    opt_state: Any
+    state_shardings: Any
+    build: Callable  # plan -> (step_fn, pipeline), see make_build
+
+
+def setup(cfg, args, interpret=None) -> Setup:
+    """Mesh, plan, initial state and the runtime factory for ``cfg`` under
+    ``args`` (``interpret``: see :func:`build_executor`)."""
     mesh = build_mesh(args)
     dp = mesh_lib.data_parallel_size(mesh)
     tp = mesh_lib.axis_size(mesh, mesh_lib.MODEL_AXIS)
@@ -351,14 +390,13 @@ def main():
     host_dp = args.mesh != "production" and (dp > 1 or tp > 1)
     opt = default_optimizer(args)
     plan = build_plan(cfg, args, optimizer=opt, mesh=mesh)
-    print(plan.describe(), flush=True)
 
     init = encdec.init_params if cfg.is_encdec else transformer.init_params
     ds = LMDataset(vocab_size=cfg.vocab_size, seq_len=args.seq, seed=0)
 
     gspmd = not host_dp and args.executor != "streaming"
     if gspmd:
-        with mesh:
+        with jax.set_mesh(mesh):
             pshapes = jax.eval_shape(lambda k: init(cfg, k),
                                      jax.random.PRNGKey(0))
             pspecs = sharding.param_specs(pshapes, mesh)
@@ -371,32 +409,56 @@ def main():
                 opt_specs, mesh))(params)
         state_shardings = {"params": sharding.named(pspecs, mesh),
                            "opt_state": sharding.named(opt_specs, mesh)}
+    elif host_dp:
+        # the shard_map executors take the state replicated: create it
+        # there, not on one device whose copy would stay resident beside
+        # the mesh's during the first step (out of memory at 2:2 on v5e)
+        state_shardings = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec())
+        params = jax.jit(lambda k: init(cfg, k),
+                         out_shardings=state_shardings)(jax.random.PRNGKey(0))
+        opt_state = jax.jit(opt.init, out_shardings=state_shardings)(params)
     else:
         params = init(cfg, jax.random.PRNGKey(0))
         opt_state = opt.init(params)
         state_shardings = None
 
-    build = make_build(cfg, args, ds, mesh, host_dp, opt)
+    build = make_build(cfg, args, ds, mesh, host_dp, opt, interpret=interpret)
+    return Setup(mesh, opt, plan, params, opt_state, state_shardings, build)
+
+
+def main(argv=None):
+    """Train per ``argv``; returns the last step's metrics (host floats)."""
+    args = parse_args(argv)
+    compile_cache.enable()
+    if args.tuning_cache:
+        # one cache serves both halves: the planner's memory correction
+        # (threaded through build_plan) and the kernels' tuned launch
+        # blocks (resolved through the process-wide active cache)
+        engine.set_cache_path(args.tuning_cache)
+
+    cfg = configs.get_reduced(args.arch) if args.reduced else configs.get(args.arch)
+    run = setup(cfg, args)
+    print(run.plan.describe(), flush=True)
 
     if args.supervise:
         supervisor = engine.Supervisor(
-            build, plan,
+            run.build, run.plan,
             config=engine.SupervisorConfig(max_restarts=args.max_restarts,
                                            on_nan=args.on_nan),
             ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
             ckpt_keep=args.ckpt_keep, log_every=args.log_every,
-            state_shardings=state_shardings,
-            plan_ctx=make_plan_ctx(cfg, args, mesh, opt))
-        run_supervised(supervisor, params, opt_state, args)
-        return
+            state_shardings=run.state_shardings,
+            plan_ctx=make_plan_ctx(cfg, args, run.mesh, run.opt))
+        return run_supervised(supervisor, run.params, run.opt_state, args)[2]
 
-    step_fn, pipeline = build(plan)
+    step_fn, pipeline = run.build(run.plan)
     trainer = engine.Trainer(step_fn, pipeline, ckpt_dir=args.ckpt_dir,
                              ckpt_every=args.ckpt_every,
                              ckpt_keep=args.ckpt_keep,
                              log_every=args.log_every,
-                             state_shardings=state_shardings)
-    run_trainer(trainer, params, opt_state, args)
+                             state_shardings=run.state_shardings)
+    return run_trainer(trainer, run.params, run.opt_state, args)[2]
 
 
 if __name__ == "__main__":

@@ -12,29 +12,30 @@ import jax
 import jax.numpy as jnp
 
 
-def current_mesh():
-    """The physical mesh of the enclosing ``with mesh:`` context (or None)."""
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
+def auto_axes() -> dict:
+    """``{name: size}`` of the ambient mesh's Auto axes (``jax.set_mesh``),
+    the axes a sharding hint may name. Empty with no mesh; a ``shard_map``
+    body sees its axes as Manual (each body holds its local shard), so
+    they are left out too."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return {n: s for n, s, t in zip(mesh.axis_names, mesh.axis_sizes,
+                                    mesh.axis_types)
+            if t == jax.sharding.AxisType.Auto}
 
 
 def shard_hint(x, *axes):
     """Best-effort ``with_sharding_constraint``: applies only when a mesh
     context is active; axis names absent from the mesh are dropped from the
     spec (so the same model code runs on any mesh or none at all)."""
-    mesh = current_mesh()
-    if mesh is None:
+    present_axes = auto_axes()
+    if not present_axes:
         return x
 
     def filt(a):
         if a is None:
             return None
         names = a if isinstance(a, tuple) else (a,)
-        present = tuple(n for n in names if n in mesh.axis_names)
+        present = tuple(n for n in names if n in present_axes)
         if not present:
             return None
         return present if len(present) > 1 else present[0]
@@ -45,10 +46,7 @@ def shard_hint(x, *axes):
 
 
 def mesh_axis_size(name: str) -> int:
-    mesh = current_mesh()
-    if mesh is None or name not in mesh.axis_names:
-        return 1
-    return mesh.shape[name]
+    return auto_axes().get(name, 1)
 
 
 _SEQ_STATE = {"enabled": None}  # per-trace override (set by forward())

@@ -15,7 +15,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(arch, shape, extra=()):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("JAX_PLATFORMS", None)
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--reduced", "--no-probe", *extra]
     proc = subprocess.run(cmd, capture_output=True, text=True, timeout=1200,

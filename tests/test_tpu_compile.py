@@ -1,0 +1,140 @@
+"""Compiles for one TPU v5e chip, described rather than attached.
+
+The kernels of the training path and the flat executor's whole train step
+are compiled by the chip's own compiler at qwen2-1.5b's published widths,
+with ``interpret=False``. Interpret mode (what every other test runs)
+cannot see what this compiler refuses: tiling, fast-memory limits and
+programs that do not fit the device. Nothing runs, so these tests say
+nothing about results or times.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU's library.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs
+from repro.analysis import hlo_checks
+from repro.kernels import fused_update, grad_accum
+from repro.launch import train
+from repro.models import transformer
+
+V5E_HBM = 16 * 1024 ** 3
+RAGGED = 1_000_003  # not a multiple of any launch block
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """``devices[0]`` of the described v5e, with the persistent compile
+    cache off: an executable for a chip that is not attached can be
+    written to the cache but not read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _qwen_layer_cfg():
+    return dataclasses.replace(configs.get("qwen2-1.5b"), num_layers=1)
+
+
+def _layer_elements() -> int:
+    """One qwen2-1.5b layer's flat bucket (46.8M fp32 elements)."""
+    p = jax.eval_shape(lambda k: transformer.init_params(_qwen_layer_cfg(), k),
+                       jax.random.PRNGKey(0))
+    return sum(x.size for x in jax.tree.leaves(p["blocks"]))
+
+
+def _size(which: str) -> int:
+    return _layer_elements() if which == "layer" else RAGGED
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _assert_on_chip(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+    assert hlo_checks.measured_peak_bytes(compiled) < V5E_HBM
+
+
+def test_layer_bucket_size():
+    assert 46_700_000 < _layer_elements() < 46_900_000
+
+
+@pytest.mark.parametrize("which", ["layer", "ragged"])
+@pytest.mark.parametrize("grad_dtype", [jnp.float32, jnp.bfloat16])
+def test_grad_accum_compiles(one_chip, which, grad_dtype):
+    n = _size(which)
+    acc = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((n,), grad_dtype, sharding=one_chip)
+    _assert_on_chip(_compile(
+        lambda a, b: grad_accum(a, b, 0.125, interpret=False),
+        acc, g))
+
+
+@pytest.mark.parametrize("which", ["layer", "ragged"])
+def test_fused_sgd_momentum_compiles(one_chip, which):
+    s = jax.ShapeDtypeStruct((_size(which),), jnp.float32, sharding=one_chip)
+    _assert_on_chip(_compile(
+        lambda p, g, m: fused_update.fused_sgd(
+            p, g, m, 0.05, momentum=0.9, weight_decay=5e-4,
+            interpret=False),
+        s, s, s))
+
+
+@pytest.mark.parametrize("which", ["layer", "ragged"])
+def test_fused_adam_compiles(one_chip, which):
+    s = jax.ShapeDtypeStruct((_size(which),), jnp.float32, sharding=one_chip)
+    _assert_on_chip(_compile(
+        lambda p, g, m, v: fused_update.fused_adam(
+            p, g, m, v, 1e-3, 0.1, 0.001, interpret=False),
+        s, s, s, s))
+
+
+def test_flat_train_step_compiles(one_chip):
+    """The flat executor's whole step (the launcher's build path) for one
+    qwen2-1.5b layer at published widths, seq 2048, micro-batch 1."""
+    cfg = _qwen_layer_cfg()
+    args = train.parse_args([
+        "--arch", "qwen2-1.5b", "--executor", "flat", "--dtype", "bfloat16",
+        "--seq", "2048", "--mini-batch", "2", "--microbatches", "2",
+        "--remat-policy", "period", "--calibrate", "off"])
+    opt = train.default_optimizer(args)
+    plan = train.build_plan(cfg, args, optimizer=opt)
+    executor, _ = train.build_executor(cfg, plan, args, optimizer=opt,
+                                       interpret=False)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda k: transformer.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    n, micro = plan.num_micro_batches, plan.micro_batch_size
+    batch = {"tokens": jax.ShapeDtypeStruct((n, micro, 2048), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((n, micro, 2048), jnp.int32),
+             "sample_weight": jax.ShapeDtypeStruct((n, micro), jnp.float32)}
+    params, opt_state, batch = (jax.tree.map(on_chip, t)
+                                for t in (params, opt_state, batch))
+    compiled = executor.lower_step(params, opt_state, batch).compile()
+    _assert_on_chip(compiled)
