@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from ..kernels import fused_update, grad_accum_buckets, grad_accum_tree
 from .flat import FlatSpec
 
@@ -81,6 +82,7 @@ def deferred_scale(normalization: str, n_s, total_valid):
     return 1.0 / total_valid
 
 
+@jax.named_scope(spans.ACCUMULATE)
 def accumulate(acc, grads, *, scale=None, fused: bool = False,
                interpret: Optional[bool] = None, block: Optional[int] = None):
     """acc ← acc + [scale ·] grads, in the accumulator's dtype.
@@ -106,6 +108,7 @@ def metrics_zeros(loss_fn: Callable, normalization: str, params, mb0):
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
+@jax.named_scope(spans.UPDATE)
 def apply_update(optimizer, grads, opt_state, params):
     """Paper Fig. 2 step ❺: one optimizer update per mini-batch."""
     updates, new_opt_state = optimizer.update(grads, opt_state, params)
@@ -114,6 +117,7 @@ def apply_update(optimizer, grads, opt_state, params):
     return new_params, new_opt_state
 
 
+@jax.named_scope(spans.ACCUMULATE)
 def accumulate_flat(acc_buffers, spec: FlatSpec, grads, *, scale=None,
                     interpret: Optional[bool] = None,
                     block: Optional[int] = None):
@@ -128,6 +132,7 @@ def accumulate_flat(acc_buffers, spec: FlatSpec, grads, *, scale=None,
                               1.0 if scale is None else scale, **kw)
 
 
+@jax.named_scope(spans.UPDATE)
 def apply_update_flat(optimizer, spec: FlatSpec, acc_buffers, opt_state,
                       params, *, interpret: Optional[bool] = None,
                       block: Optional[int] = None):
@@ -210,6 +215,7 @@ def finite_all(grads) -> jnp.ndarray:
     return ok
 
 
+@jax.named_scope(spans.UPDATE)
 def guarded_update(optimizer, grads, opt_state, params):
     """Step ❺ behind the finite-check: if the accumulated gradient has any
     non-finite element, skip the update (params + opt state pass through
@@ -228,6 +234,7 @@ def guarded_update(optimizer, grads, opt_state, params):
     return new_params, new_opt_state, ok
 
 
+@jax.named_scope(spans.UPDATE)
 def guarded_update_flat(optimizer, spec: FlatSpec, acc_buffers, opt_state,
                         params, *, interpret: Optional[bool] = None,
                         block: Optional[int] = None):

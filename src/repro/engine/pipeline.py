@@ -26,6 +26,7 @@ from typing import Any, Dict, Iterator, Optional
 
 import jax
 
+from .. import spans
 from ..core.streaming import prefetch_iterator
 from . import faults
 from .plan import MBSPlan
@@ -169,14 +170,16 @@ class Pipeline:
         """Pull + stage the next batch, charging the blocked time to
         ``stats.wait_s``. The device_put returns immediately (async
         transfer) — by staging batch i+1 before yielding batch i we get
-        the double buffer."""
-        t0 = time.perf_counter()
-        try:
-            staged = self._put(next(it))
-        except StopIteration:
-            return _DONE
-        finally:
-            stats.wait_s += time.perf_counter() - t0
+        the double buffer. The same interval is the ``input`` span of a
+        profiler trace."""
+        with jax.profiler.TraceAnnotation(spans.INPUT):
+            t0 = time.perf_counter()
+            try:
+                staged = self._put(next(it))
+            except StopIteration:
+                return _DONE
+            finally:
+                stats.wait_s += time.perf_counter() - t0
         return staged
 
 

@@ -70,6 +70,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import spans
 from ..launch import mesh as mesh_lib
 from ..launch import sharding
 from . import exec_core, faults
@@ -289,6 +290,7 @@ class PipelinedExecutor:
             return x
         return jax.tree.map(g, tree_, specs)
 
+    @jax.named_scope(spans.GRAD_SYNC)
     def _scatter_grads(self, tree_, specs, *, sum_model: bool):
         """Reduce full gradients back to the FSDP layout: ``psum_scatter``
         on sharded dims, plain data psum on unsharded leaves. ``sum_model``

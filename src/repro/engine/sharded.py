@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import spans
 from ..launch import mesh as mesh_lib
 from . import exec_core, faults, flat as flat_lib
 from .executors import EXECUTORS, _as_plan, get_executor
@@ -64,6 +65,7 @@ def _axis_entry(axes: Tuple[str, ...]):
     return axes if len(axes) > 1 else axes[0]
 
 
+@jax.named_scope(spans.GRAD_SYNC)
 def psum_flat(tree, axis_names):
     """One collective for a whole pytree: ravel every leaf into a single
     fp32 buffer, ``lax.psum`` it once, unpack. This is why the deferred
@@ -408,8 +410,9 @@ class ShardedExecutor:
             lfn = exec_core.micro_loss_fn(self.loss_fn, "exact", 1, 1.0, mb,
                                           defer_scale=True)
             (l, metrics), g = jax.value_and_grad(lfn, has_aux=True)(params)
-            acc = jax.tree.map(lambda a, gg: a + gg.astype(a.dtype)[None],
-                               acc, g)
+            with jax.named_scope(spans.ACCUMULATE):
+                acc = jax.tree.map(
+                    lambda a, gg: a + gg.astype(a.dtype)[None], acc, g)
             metric_sum = jax.tree.map(lambda s, m: s + m[None],
                                       metric_sum, metrics)
             return (acc, loss_sum + l[None], metric_sum,

@@ -18,6 +18,13 @@ inline, with the synchronization bugs designed out:
 Combined with the Pipeline's step-indexed seeding, a save → resume
 round-trip replays the identical data stream and op sequence, so it
 matches an uninterrupted run bitwise (the regression test asserts this).
+
+The loop writes the host spans of ``repro.spans`` (``dispatch``,
+``readback``, ``checkpoint``; the pipeline writes ``input``) into any
+active ``jax.profiler`` trace. With ``profile_dir`` the first ``fit``
+traces ``profile_steps`` steps itself, starting after its first step
+(which traces and compiles), and waits for them on the device before it
+stops the trace.
 """
 from __future__ import annotations
 
@@ -26,6 +33,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
+from .. import spans
 from ..checkpoint import checkpoint
 from .pipeline import Pipeline
 
@@ -45,7 +53,10 @@ class Trainer:
     def __init__(self, step_fn: Callable, pipeline: Pipeline, *,
                  ckpt_dir: Optional[str] = None, ckpt_every: int = 0,
                  ckpt_keep: Optional[int] = None, log_every: int = 5,
-                 state_shardings: Any = None, log_fn: Callable = _default_log):
+                 state_shardings: Any = None, log_fn: Callable = _default_log,
+                 profile_dir: Optional[str] = None, profile_steps: int = 5):
+        if profile_dir and profile_steps < 1:
+            raise ValueError(f"profile_steps must be >= 1, got {profile_steps}")
         self.step_fn = step_fn
         self.pipeline = pipeline
         self.ckpt_dir = ckpt_dir
@@ -54,15 +65,18 @@ class Trainer:
         self.log_every = log_every
         self.state_shardings = state_shardings
         self.log_fn = log_fn
+        self.profile_dir = profile_dir
+        self.profile_steps = profile_steps
 
     # -- checkpointing ------------------------------------------------------
 
     def save(self, step: int, params, opt_state) -> Optional[str]:
         if not self.ckpt_dir:
             return None
-        return checkpoint.save(self.ckpt_dir, step,
-                               {"params": params, "opt_state": opt_state},
-                               keep=self.ckpt_keep)
+        with jax.profiler.TraceAnnotation(spans.CHECKPOINT):
+            return checkpoint.save(self.ckpt_dir, step,
+                                   {"params": params, "opt_state": opt_state},
+                                   keep=self.ckpt_keep)
 
     def restore(self, params_template, opt_state_template
                 ) -> Optional[Tuple[Any, Any, int]]:
@@ -115,19 +129,39 @@ class Trainer:
         last: Dict[str, float] = {}
         stream = self.pipeline.batches(num_steps - start_step,
                                        start=start_step)
-        # drive iteration from the stream (not a zip'd range) so the
-        # generator runs to completion and finalizes pipeline.stats
-        for offset, batch in enumerate(stream):
-            step = start_step + offset
-            params, opt_state, metrics = self.step_fn(params, opt_state, batch)
-            # read back the PREVIOUS step's metrics now that this step is
-            # in flight — the readback overlaps compute instead of gating it
-            if pending is not None:
-                self._flush(pending, t0)
-            pending = (step, metrics)
-            if self.ckpt_every and (step + 1) % self.ckpt_every == 0 \
-                    and step + 1 < num_steps:
-                self.save(step + 1, params, opt_state)
+        # steps [1, 1 + profile_steps) of this pass, once per Trainer
+        trace_from = 1 if self.profile_dir else None
+        tracing = False
+        try:
+            # drive iteration from the stream (not a zip'd range) so the
+            # generator runs to completion and finalizes pipeline.stats
+            for offset, batch in enumerate(stream):
+                step = start_step + offset
+                if offset == trace_from:
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0  # spans only, not frames
+                    jax.profiler.start_trace(self.profile_dir,
+                                             profiler_options=opts)
+                    tracing = True
+                elif tracing and offset == trace_from + self.profile_steps:
+                    tracing = self._stop_trace(params, opt_state)
+                with jax.profiler.TraceAnnotation(spans.DISPATCH):
+                    params, opt_state, metrics = self.step_fn(
+                        params, opt_state, batch)
+                # read back the PREVIOUS step's metrics now that this step
+                # is in flight — the readback overlaps compute instead of
+                # gating it
+                if pending is not None:
+                    self._flush(pending, t0)
+                pending = (step, metrics)
+                if self.ckpt_every and (step + 1) % self.ckpt_every == 0 \
+                        and step + 1 < num_steps:
+                    self.save(step + 1, params, opt_state)
+            if tracing:
+                tracing = self._stop_trace(params, opt_state)
+        finally:
+            if tracing:  # an exception left the trace open
+                jax.profiler.stop_trace()
         if pending is not None:
             last = self._readback(pending[1])
             if self.log_fn:
@@ -142,6 +176,15 @@ class Trainer:
             self.log_fn(step, self._readback(metrics),
                         time.perf_counter() - t0)
 
+    def _stop_trace(self, params, opt_state) -> bool:
+        """Wait for the traced steps on the device, stop the trace and
+        profile no later pass; returns the new ``tracing`` flag."""
+        jax.block_until_ready((params, opt_state))
+        jax.profiler.stop_trace()
+        self.profile_dir = None
+        return False
+
     @staticmethod
     def _readback(metrics: Dict[str, Any]) -> Dict[str, float]:
-        return {k: float(v) for k, v in metrics.items()}
+        with jax.profiler.TraceAnnotation(spans.READBACK):
+            return {k: float(v) for k, v in metrics.items()}

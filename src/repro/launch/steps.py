@@ -17,7 +17,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import engine
+from .. import engine, spans
 from ..configs.shapes import InputShape
 from ..core import losses
 from ..models import encdec, nn, transformer
@@ -91,8 +91,10 @@ def make_loss_fn(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
                 vision_embeds=mb.get("vision_embeds"),
                 mrope_positions=mb.get("mrope_positions"),
                 dtype=dtype, remat_policy=policy, scan_unroll=scan_unroll)
-        loss = losses.cross_entropy(logits, mb["labels"], sample_weight=sw,
-                                    exact_denom=exact_denom)
+        with jax.named_scope(spans.HEAD):
+            loss = losses.cross_entropy(logits, mb["labels"],
+                                        sample_weight=sw,
+                                        exact_denom=exact_denom)
         if cfg.is_moe:
             aux_term = cfg.router_aux_coef * aux / cfg.num_layers
             # exact-mode contract: micro contributions SUM to the mini-batch
@@ -146,10 +148,12 @@ def make_staged_loss(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
         B, S = x.shape[:2]
         return jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
 
+    @jax.named_scope(spans.TRUNK)
     def prelude(shared, mb):
         return transformer._embed_inputs(shared, cfg, mb["tokens"], None,
                                          dtype)
 
+    @jax.named_scope(spans.TRUNK)
     def stage_fn(stage_p, x):
         positions = _positions(x)
 
@@ -166,8 +170,8 @@ def make_staged_loss(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
         x, _ = jax.lax.scan(period_fn, x, stage_p, unroll=scan_unroll)
         return x
 
-    def finale(shared, x, mb):
-        x = nn.rmsnorm(shared["final_norm"], x, cfg.norm_eps)
+    @jax.named_scope(spans.HEAD)
+    def head(shared, x, mb):
         if cfg.tie_embeddings:
             logits = nn.unembed(shared["embed"], x, jnp.float32)
         else:
@@ -177,6 +181,11 @@ def make_staged_loss(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
                                     sample_weight=mb.get("sample_weight"),
                                     exact_denom=1.0)
         return loss, {}
+
+    def finale(shared, x, mb):
+        with jax.named_scope(spans.TRUNK):
+            x = nn.rmsnorm(shared["final_norm"], x, cfg.norm_eps)
+        return head(shared, x, mb)
 
     return engine.StagedLoss(num_layers=cfg.num_periods, prelude=prelude,
                              stage_fn=stage_fn, finale=finale,

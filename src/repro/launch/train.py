@@ -23,11 +23,19 @@ state, non-finite steps are retried/skipped per ``--on-nan``, and
 supervisor give-ups map onto the documented exit codes (40–44,
 DESIGN.md §Fault tolerance).
 
+With ``--profile-dir DIR`` the ``Trainer`` writes a ``jax.profiler``
+trace of ``--profile-steps`` steps, taken after the first step compiled,
+into DIR (``DIR/plugins/profile/<time>/*.xplane.pb``). The trace holds
+the host spans and the step's device phases named in ``repro.spans``;
+``python3 -m bench.phases DIR`` (from the checkout's root) reduces it to
+each phase's share of the device time.
+
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
       --reduced --steps 20 --mini-batch 16 [--microbatches 4] \
       [--executor compiled|streaming|fused] \
       [--ckpt-dir /tmp/ckpt --ckpt-every 10 --resume] \
-      [--supervise --max-restarts 3 --on-nan skip --ckpt-keep 3]
+      [--supervise --max-restarts 3 --on-nan skip --ckpt-keep 3] \
+      [--profile-dir /tmp/prof --profile-steps 5]
 """
 from __future__ import annotations
 
@@ -231,6 +239,9 @@ def run_trainer(trainer, params, opt_state, args):
                                           start_step=start)
     if args.ckpt_dir:
         print(f"checkpointed to {args.ckpt_dir}", flush=True)
+    if args.profile_dir:
+        print(f"profiled to {args.profile_dir}" if trainer.profile_dir is None
+              else "not profiled: the run has fewer than 2 steps", flush=True)
     stats = trainer.pipeline.stats
     print(f"input-wait fraction {stats.input_wait_fraction:.3f} "
           f"({stats.wait_s:.2f}s of {stats.elapsed_s:.2f}s, "
@@ -340,6 +351,12 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="host batches buffered by the input pipeline "
                          "(0: synchronous)")
     ap.add_argument("--log-every", type=int, default=5)
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="write a jax.profiler trace of --profile-steps "
+                         "steps, taken after the first step compiled, "
+                         "into DIR")
+    ap.add_argument("--profile-steps", type=int, default=5, metavar="N",
+                    help="steps traced with --profile-dir (default 5)")
     ap.add_argument("--dtype", choices=["float32", "bfloat16"],
                     default="float32")
     args = ap.parse_args(argv)
@@ -364,6 +381,11 @@ def parse_args(argv=None) -> argparse.Namespace:
                  "'DATA:MODEL' mesh spec with MODEL > 1")
     if args.resume and not args.ckpt_dir:
         ap.error("--resume needs --ckpt-dir")
+    if args.profile_dir and args.supervise:
+        ap.error("--profile-dir traces the plain Trainer loop; the "
+                 "--supervise loop is not traced")
+    if args.profile_steps < 1:
+        ap.error("--profile-steps must be >= 1")
     return args
 
 
@@ -457,7 +479,9 @@ def main(argv=None):
                              ckpt_every=args.ckpt_every,
                              ckpt_keep=args.ckpt_keep,
                              log_every=args.log_every,
-                             state_shardings=run.state_shardings)
+                             state_shardings=run.state_shardings,
+                             profile_dir=args.profile_dir,
+                             profile_steps=args.profile_steps)
     return run_trainer(trainer, run.params, run.opt_state, args)[2]
 
 

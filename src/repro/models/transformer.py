@@ -15,6 +15,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from . import attention, moe, nn, recurrent, ssm
 from . import remat as remat_lib
 from .config import ModelConfig
@@ -187,28 +188,29 @@ def forward(params, cfg: ModelConfig, tokens, *, positions=None,
     # for MoE (see nn.set_seq_shard) — gate by family
     nn.set_seq_shard(False if cfg.is_moe else None)
     try:
-        x = nn.seq_sharded(_embed_inputs(params, cfg, tokens, vision_embeds,
-                                         dtype))
+        with jax.named_scope(spans.TRUNK):
+            x = nn.seq_sharded(_embed_inputs(params, cfg, tokens,
+                                             vision_embeds, dtype))
 
-        def period_fn(x, slot_params):
-            aux_total = jnp.zeros((), jnp.float32)
-            for kind, p in zip(cfg.layer_pattern, slot_params):
-                x, aux, _ = _apply_slot(p, cfg, kind, x, positions,
-                                        dtype=dtype,
-                                        global_window=global_window,
-                                        mrope_positions=mrope_positions,
-                                        remat_policy=policy)
-                aux_total = aux_total + aux
-            return x, aux_total
+            def period_fn(x, slot_params):
+                aux_total = jnp.zeros((), jnp.float32)
+                for kind, p in zip(cfg.layer_pattern, slot_params):
+                    x, aux, _ = _apply_slot(p, cfg, kind, x, positions,
+                                            dtype=dtype,
+                                            global_window=global_window,
+                                            mrope_positions=mrope_positions,
+                                            remat_policy=policy)
+                    aux_total = aux_total + aux
+                return x, aux_total
 
-        period_fn = remat_lib.checkpoint_period(period_fn, policy)
+            period_fn = remat_lib.checkpoint_period(period_fn, policy)
 
-        def scan_body(x, slot_params):
-            return period_fn(x, slot_params)
+            def scan_body(x, slot_params):
+                return period_fn(x, slot_params)
 
-        x, aux = jax.lax.scan(scan_body, x, params["blocks"],
-                              unroll=scan_unroll)
-        x = nn.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            x, aux = jax.lax.scan(scan_body, x, params["blocks"],
+                                  unroll=scan_unroll)
+            x = nn.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         if return_hidden:
             return x, jnp.sum(aux)
         logits = _lm_head(params, cfg, x)
@@ -217,6 +219,7 @@ def forward(params, cfg: ModelConfig, tokens, *, positions=None,
         nn.set_seq_shard(None)
 
 
+@jax.named_scope(spans.HEAD)
 def _lm_head(params, cfg: ModelConfig, x):
     if cfg.tie_embeddings:
         logits = nn.unembed(params["embed"], x, jnp.float32)
