@@ -69,6 +69,21 @@ def abstract_opt_state(optimizer, params_shapes):
 # loss / train step
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(spans.HEAD)
+def head_loss(params, cfg: ModelConfig, x, labels, *, sample_weight=None,
+              exact_denom=None):
+    """The training loss on the final hidden states ``x``: the output head
+    (tied or not, with ``cfg.final_softcap``) and its cross-entropy as one
+    op, ``losses.lm_head_cross_entropy``. ``params`` holds ``embed`` and,
+    untied, ``unembed`` (the whole parameter tree, or a staged loss's
+    shared part)."""
+    w = (params["embed"]["table"] if cfg.tie_embeddings
+         else params["unembed"]["w"])
+    return losses.lm_head_cross_entropy(
+        x, w, labels, tied=cfg.tie_embeddings, softcap=cfg.final_softcap,
+        sample_weight=sample_weight, exact_denom=exact_denom)
+
+
 def make_loss_fn(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
                  scan_unroll: int = 1,
                  remat_policy: Optional[str] = None):
@@ -81,20 +96,20 @@ def make_loss_fn(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
     def loss_fn(params, mb, exact_denom=None):
         sw = mb.get("sample_weight")
         if cfg.is_encdec:
-            logits, aux = encdec.forward(params, cfg, mb["frames"],
-                                         mb["tgt_tokens"], dtype=dtype,
-                                         remat_policy=policy,
-                                         scan_unroll=scan_unroll)
+            x, aux = encdec.forward(params, cfg, mb["frames"],
+                                    mb["tgt_tokens"], dtype=dtype,
+                                    remat_policy=policy,
+                                    scan_unroll=scan_unroll,
+                                    return_hidden=True)
         else:
-            logits, aux = transformer.forward(
+            x, aux = transformer.forward(
                 params, cfg, mb["tokens"],
                 vision_embeds=mb.get("vision_embeds"),
                 mrope_positions=mb.get("mrope_positions"),
-                dtype=dtype, remat_policy=policy, scan_unroll=scan_unroll)
-        with jax.named_scope(spans.HEAD):
-            loss = losses.cross_entropy(logits, mb["labels"],
-                                        sample_weight=sw,
-                                        exact_denom=exact_denom)
+                dtype=dtype, remat_policy=policy, scan_unroll=scan_unroll,
+                return_hidden=True)
+        loss = head_loss(params, cfg, x, mb["labels"], sample_weight=sw,
+                         exact_denom=exact_denom)
         if cfg.is_moe:
             aux_term = cfg.router_aux_coef * aux / cfg.num_layers
             # exact-mode contract: micro contributions SUM to the mini-batch
@@ -170,22 +185,12 @@ def make_staged_loss(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
         x, _ = jax.lax.scan(period_fn, x, stage_p, unroll=scan_unroll)
         return x
 
-    @jax.named_scope(spans.HEAD)
-    def head(shared, x, mb):
-        if cfg.tie_embeddings:
-            logits = nn.unembed(shared["embed"], x, jnp.float32)
-        else:
-            logits = nn.dense(shared["unembed"], x, jnp.float32)
-        logits = nn.softcap(logits, cfg.final_softcap)
-        loss = losses.cross_entropy(logits, mb["labels"],
-                                    sample_weight=mb.get("sample_weight"),
-                                    exact_denom=1.0)
-        return loss, {}
-
     def finale(shared, x, mb):
         with jax.named_scope(spans.TRUNK):
             x = nn.rmsnorm(shared["final_norm"], x, cfg.norm_eps)
-        return head(shared, x, mb)
+        return head_loss(shared, cfg, x, mb["labels"],
+                         sample_weight=mb.get("sample_weight"),
+                         exact_denom=1.0), {}
 
     return engine.StagedLoss(num_layers=cfg.num_periods, prelude=prelude,
                              stage_fn=stage_fn, finale=finale,

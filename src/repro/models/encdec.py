@@ -92,8 +92,11 @@ def encode(params, cfg: ModelConfig, frames, *, dtype=jnp.bfloat16,
 
 def forward(params, cfg: ModelConfig, frames, tgt_tokens, *,
             dtype=jnp.bfloat16, remat: bool = True,
-            remat_policy: Optional[str] = None, scan_unroll: int = 1):
-    """Teacher-forced forward. Returns (logits (B, S_dec, V), aux=0)."""
+            remat_policy: Optional[str] = None, scan_unroll: int = 1,
+            return_hidden: bool = False):
+    """Teacher-forced forward. Returns (logits (B, S_dec, V), aux=0), or
+    with ``return_hidden`` the decoder's final hidden states (B, S_dec,
+    d_model) in place of the logits."""
     policy = remat_lib.resolve(remat, remat_policy)
     enc_out = encode(params, cfg, frames, dtype=dtype, remat_policy=policy,
                      scan_unroll=scan_unroll)
@@ -125,6 +128,8 @@ def forward(params, cfg: ModelConfig, frames, tgt_tokens, *,
     layer = remat_lib.checkpoint_period(layer, policy)
     x, _ = jax.lax.scan(layer, x, params["dec_layers"], unroll=scan_unroll)
     x = nn.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    if return_hidden:
+        return x, jnp.zeros((), jnp.float32)
     logits = nn.unembed(params["embed"], x, jnp.float32)
     return nn.softcap(logits, cfg.final_softcap), jnp.zeros((), jnp.float32)
 

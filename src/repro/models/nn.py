@@ -95,6 +95,17 @@ def seq_gathered(x):
     return shard_hint(x, *spec)
 
 
+def vocab_sharded(logits):
+    """Vocab-sharded (Megatron-style) logits (B, ..., V): with the embedding
+    table sharded on V, the head emits V/TP-sharded logits (the batch stays
+    data-sharded) and the reductions over V run shardedly, never
+    materializing (or all-reducing) a full-vocab tensor."""
+    spec = [None] * logits.ndim
+    spec[0] = ("pod", "data")
+    spec[-1] = "model"
+    return shard_hint(logits, *spec)
+
+
 def dense_init(key, in_dim: int, out_dim: int, *, bias: bool = False,
                scale: Optional[float] = None, dtype=jnp.float32):
     scale = (1.0 / math.sqrt(in_dim)) if scale is None else scale

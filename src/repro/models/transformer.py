@@ -225,15 +225,7 @@ def _lm_head(params, cfg: ModelConfig, x):
         logits = nn.unembed(params["embed"], x, jnp.float32)
     else:
         logits = nn.dense(params["unembed"], x, jnp.float32)
-    # vocab-sharded logits (Megatron-style): with the embedding table sharded
-    # on V, the head emits V/TP-sharded logits (batch stays data-sharded) and
-    # the CE reduces shardedly — never materializing (or all-reducing) a
-    # full-vocab logits tensor.
-    spec = [None] * logits.ndim
-    spec[0] = ("pod", "data")
-    spec[-1] = "model"
-    logits = nn.shard_hint(logits, *spec)
-    return nn.softcap(logits, cfg.final_softcap)
+    return nn.softcap(nn.vocab_sharded(logits), cfg.final_softcap)
 
 
 def supports_ragged_prefill(cfg: ModelConfig) -> bool:

@@ -15,8 +15,9 @@ program is otherwise the same. Each is placed once, where the work is:
                   of every loop, so that word would name every op of the
                   micro-batch scan;
   ``head``        the output head with its softcap and the cross-entropy
-                  on its logits (``transformer._lm_head`` and the loss in
-                  ``steps.make_loss_fn`` and the staged finale);
+                  on its logits: in training one op, ``steps.head_loss``
+                  (the loss of ``steps.make_loss_fn`` and of the staged
+                  finale); in serving ``transformer._lm_head``;
   ``accumulate``  adding a micro-batch's gradient into the accumulator
                   (``exec_core.accumulate``, ``accumulate_flat``: the plain
                   add and the Pallas kernel alike);

@@ -11,12 +11,15 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU's library.
 """
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro import configs
 from repro.analysis import hlo_checks
@@ -25,6 +28,9 @@ from repro.launch import train
 from repro.models import transformer
 
 V5E_HBM = 16 * 1024 ** 3
+# ``memory_stats()["bytes_limit"]`` of one TPU v5 lite chip, read on the
+# chip (the described topology reports none)
+V5E_BYTES_LIMIT = 16_909_336_064
 RAGGED = 1_000_003  # not a multiple of any launch block
 
 
@@ -138,3 +144,77 @@ def test_flat_train_step_compiles(one_chip):
                                 for t in (params, opt_state, batch))
     compiled = executor.lower_step(params, opt_state, batch).compile()
     _assert_on_chip(compiled)
+
+
+def _top_level_arrays(hlo: str):
+    """``(dtype, elements, op)`` of every array an instruction of the
+    optimized HLO materializes: fusion bodies and aliasing ops (bitcast,
+    get-tuple-element, tuple, parameter) are left out."""
+    bodies = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", hlo))
+    out, skip = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            skip = head.group(1) in bodies
+            continue
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([\w\-]+)\(", line)
+        if skip or not m or m.group(2) in (
+                "bitcast", "get-tuple-element", "tuple", "parameter"):
+            continue
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", m.group(1)):
+            out.append((dtype, math.prod(int(d) for d in dims.split(",") if d),
+                        m.group(2)))
+    return out
+
+
+@pytest.mark.parametrize("seq", [2048, 4096])
+def test_mbp_step_head_keeps_one_fp32_logits(topo, one_chip, capsys, seq):
+    """The ``qwen2-1.5b-8l`` MBP step (8 micro-batches of 1, remat
+    ``period``) as the launcher builds it. Its head is one custom-VJP op:
+    the optimized program holds one fp32 buffer of the seq x 151,936
+    logits (the forward dot's output) and no scatter into one, and the
+    compiler's arguments + temporaries stay under the chip's bytes limit
+    at seq 4096 too. That figure is a memory guard, not the chip's own
+    test, which may admit a program somewhat above it."""
+    cfg = dataclasses.replace(configs.get("qwen2-1.5b"), num_layers=8)
+    args = train.parse_args([
+        "--arch", "qwen2-1.5b", "--dtype", "bfloat16", "--seq", str(seq),
+        "--mini-batch", "8", "--microbatches", "8", "--remat-policy",
+        "period", "--calibrate", "off"])
+    opt = train.default_optimizer(args)
+    plan = train.build_plan(cfg, args, optimizer=opt)
+    executor, _ = train.build_executor(cfg, plan, args, optimizer=opt,
+                                       interpret=False)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda k: transformer.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    n, micro = plan.num_micro_batches, plan.micro_batch_size
+    assert (n, micro) == (8, 1)
+    batch = {"tokens": jax.ShapeDtypeStruct((n, micro, seq), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((n, micro, seq), jnp.int32),
+             "sample_weight": jax.ShapeDtypeStruct((n, micro), jnp.float32)}
+    params, opt_state, batch = (jax.tree.map(on_chip, t)
+                                for t in (params, opt_state, batch))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    jitted = jax.jit(executor.make_train_step(), donate_argnums=(0, 1, 2))
+    with jax.set_mesh(mesh):
+        compiled = jitted.lower(params, opt_state, batch).compile()
+    hlo = compiled.as_text()
+    logits = micro * seq * cfg.vocab_size
+    arrays = _top_level_arrays(hlo)
+    assert [a for a in arrays if a[:2] == ("f32", logits)] == [
+        ("f32", logits, "fusion")]
+    scattered = re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(", hlo)
+    assert logits not in [math.prod(int(d) for d in dims.split(","))
+                          for dims in scattered]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    peak = hlo_checks.measured_peak_bytes(compiled)
+    with capsys.disabled():
+        print(f"\nqwen2-1.5b-8l MBP step at seq {seq} for v5e: temp "
+              f"{temp / 2 ** 30:.3f} GiB, peak {peak / 2 ** 30:.3f} GiB")
+    assert peak < V5E_BYTES_LIMIT
