@@ -10,7 +10,7 @@ def config() -> ModelConfig:
         head_dim=0, d_ff=0, vocab_size=50_280,
         layer_pattern=("ssm",),
         ssm_state=128, ssm_expand=2, ssm_head_dim=64, ssm_chunk=256,
-        conv_width=4, tie_embeddings=True,
+        conv_width=4, tie_embeddings=True, residual_in_fp32=True,
         source="arXiv:2405.21060",
     )
 
@@ -22,6 +22,6 @@ def reduced() -> ModelConfig:
         head_dim=0, d_ff=0, vocab_size=512,
         layer_pattern=("ssm",),
         ssm_state=16, ssm_expand=2, ssm_head_dim=32, ssm_chunk=8,
-        conv_width=4,
+        conv_width=4, residual_in_fp32=True,
         source="arXiv:2405.21060",
     )

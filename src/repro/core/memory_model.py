@@ -129,6 +129,12 @@ class MemoryEstimate:
         return self.total(0), self.activation_bytes_per_sample
 
 
+def _stream_bytes(cfg: ModelConfig, act_bytes: int) -> int:
+    """Bytes of one residual-stream element: fp32 where the model keeps
+    its residual stream so (``cfg.residual_in_fp32``), else ``act_bytes``."""
+    return 4 if cfg.residual_in_fp32 else act_bytes
+
+
 def activation_bytes_per_sample(cfg: ModelConfig, seq: int,
                                 act_bytes: int = 2,
                                 remat: bool = True,
@@ -152,7 +158,7 @@ def activation_bytes_per_sample(cfg: ModelConfig, seq: int,
     """
     policy = remat_lib.resolve(remat, remat_policy)
     d = cfg.d_model
-    boundary = cfg.num_periods * seq * d * act_bytes
+    boundary = cfg.num_periods * seq * d * _stream_bytes(cfg, act_bytes)
     widths = [d * 6]  # qkv + attn out + residuals
     if cfg.is_moe:
         widths.append(cfg.experts_per_token * cfg.moe_d_ff * 3 * cfg.capacity_factor)
@@ -206,7 +212,7 @@ def pipeline_activation_bytes_per_sample(cfg: ModelConfig, seq: int,
         raise ValueError(f"stages must be >= 1, got {stages}")
     policy = remat_lib.resolve(remat, remat_policy)
     d = cfg.d_model
-    carry = seq * d * act_bytes
+    carry = seq * d * _stream_bytes(cfg, act_bytes)
     rings = 2 * stages * carry
     per_stage = -(-cfg.num_periods // stages)
     widths = [d * 6]
@@ -305,7 +311,7 @@ def prefill_activation_bytes_per_sample(cfg: ModelConfig, seq: int,
     prefill *builds* are accounted by the caller through
     :func:`kv_slot_bytes` (they persist past the prefill)."""
     d = cfg.d_model
-    stream = 2 * seq * d * act_bytes
+    stream = 2 * seq * d * _stream_bytes(cfg, act_bytes)
     widths = [d * 6]
     if cfg.is_moe:
         widths.append(cfg.experts_per_token * cfg.moe_d_ff * 3

@@ -188,7 +188,7 @@ def make_staged_loss(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
     def finale(shared, x, mb):
         with jax.named_scope(spans.TRUNK):
             x = nn.rmsnorm(shared["final_norm"], x, cfg.norm_eps)
-        return head_loss(shared, cfg, x, mb["labels"],
+        return head_loss(shared, cfg, x.astype(dtype), mb["labels"],
                          sample_weight=mb.get("sample_weight"),
                          exact_denom=1.0), {}
 

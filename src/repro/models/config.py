@@ -76,6 +76,9 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
     embed_scale: bool = False  # gemma-style sqrt(d_model) embedding scale
+    # the residual stream in fp32 whatever the compute dtype (mamba_ssm's
+    # ``residual_in_fp32``); blocks and the head still compute in it
+    residual_in_fp32: bool = False
 
     source: str = ""  # citation for the config
 

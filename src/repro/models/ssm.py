@@ -16,24 +16,36 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from . import nn
 from . import remat as remat_lib
 from .config import ModelConfig
+
+
+# Mamba-2's initialization (mamba_ssm's ``Mamba2``): A ~ U[1, 16], and
+# softplus(dt_bias) log-uniform in [DT_MIN, DT_MAX], floored at DT_FLOOR.
+A_INIT_RANGE = (1.0, 16.0)
+DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
 
 
 def ssm_init(key, cfg: ModelConfig):
     d, di, N, H = cfg.d_model, cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_num_heads
     W = cfg.conv_width
     ks = jax.random.split(key, 4)
+    k_a, k_dt = jax.random.split(ks[3])
     conv_dim = di + 2 * N
+    A = jax.random.uniform(k_a, (H,), jnp.float32, *A_INIT_RANGE)
+    dt = jnp.exp(jax.random.uniform(k_dt, (H,), jnp.float32,
+                                    math.log(DT_MIN), math.log(DT_MAX)))
+    dt = jnp.maximum(dt, DT_FLOOR)
     return {
         # fused input projection: [z, x, B, C, dt]
         "in_proj": nn.dense_init(ks[0], d, 2 * di + 2 * N + H),
         "conv_w": jax.random.normal(ks[1], (W, conv_dim), jnp.float32) / math.sqrt(W),
         "conv_b": jnp.zeros((conv_dim,), jnp.float32),
-        "A_log": jnp.zeros((H,), jnp.float32),  # A = -exp(A_log) = -1 init
+        "A_log": jnp.log(A),  # A = -exp(A_log)
         "D": jnp.ones((H,), jnp.float32),
-        "dt_bias": jnp.zeros((H,), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus(dt_bias) = dt
         "out_norm": nn.rmsnorm_init(di),
         "out_proj": nn.dense_init(ks[2], di, d),
     }
@@ -56,6 +68,7 @@ def _causal_conv(xBC, conv_w, conv_b):
     return jax.nn.silu(out + conv_b.astype(xBC.dtype))
 
 
+@jax.named_scope(spans.SSD)
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None):
     """Chunked SSD. x: (B,S,H,P); dt: (B,S,H); A: (H,) negative;
     Bm, Cm: (B,S,N) (G=1, shared across heads).
@@ -80,14 +93,18 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None):
 
     a = dtc * A  # (B,nc,Q,H) log-decay per step (negative)
     cum = jnp.cumsum(a, axis=2)  # within-chunk inclusive cumsum
-    # intra-chunk (diagonal blocks): L[i,j] = exp(cum_i - cum_j) for i>=j
+    # intra-chunk (diagonal blocks): L[i,j] = exp(cum_i - cum_j) for i>=j.
+    # Above the diagonal cum_i - cum_j > 0 and exp overflows, so the mask
+    # goes in before exp: exp(-inf) = 0 forms no inf in the forward and no
+    # 0 * inf in the backward.
     seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,Q,H)
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(mask[None, None, :, :, None], jnp.exp(seg), 0.0)
+    L = jnp.exp(jnp.where(mask[None, None, :, :, None], seg, -jnp.inf))
     xdt = xc * dtc[..., None]  # (B,nc,Q,H,P)
     G = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)  # (B,nc,Q,Q)
     y_diag = jnp.einsum("bcij,bcijh,bcjhp->bcihp", G, L, xdt)
 
+    # cum falls along the chunk, so the exps below have arguments <= 0
     # chunk summary state: S_c = sum_j exp(cum_last - cum_j) B_j (x_j dt_j)^T
     decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # (B,nc,Q,H)
     states = jnp.einsum("bcjn,bcjh,bcjhp->bchpn", Bc, decay_to_end, xdt)

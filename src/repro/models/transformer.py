@@ -147,7 +147,7 @@ def _apply_slot(p, cfg: ModelConfig, kind: str, x, positions, *, dtype,
             remat_policy)(p["ffn"], h)
         return x, aux, final_h
     if kind == "ssm":
-        h = nn.rmsnorm(p["pre_norm"], x, cfg.norm_eps)
+        h = nn.rmsnorm(p["pre_norm"], x, cfg.norm_eps).astype(dtype)
         h, final = ssm.ssm_block(p["ssm"], cfg, nn.seq_gathered(h),
                                  compute_dtype=dtype,
                                  return_cache=want_cache,
@@ -156,8 +156,16 @@ def _apply_slot(p, cfg: ModelConfig, kind: str, x, positions, *, dtype,
     raise ValueError(kind)
 
 
+def residual_dtype(cfg: ModelConfig, dtype):
+    """The residual stream's dtype: fp32 where the model keeps it so
+    (``cfg.residual_in_fp32``), else the compute dtype. Blocks read it
+    through their norms and add their compute-dtype outputs to it."""
+    return jnp.float32 if cfg.residual_in_fp32 else dtype
+
+
 def _embed_inputs(params, cfg: ModelConfig, tokens, vision_embeds, dtype):
-    x = nn.embed(params["embed"], tokens, dtype, scale=cfg.embed_scale)
+    x = nn.embed(params["embed"], tokens, residual_dtype(cfg, dtype),
+                 scale=cfg.embed_scale)
     if cfg.is_vlm and vision_embeds is not None:
         vis = nn.dense(params["vision_proj"], vision_embeds, dtype)
         if cfg.embed_scale:
@@ -212,7 +220,8 @@ def forward(params, cfg: ModelConfig, tokens, *, positions=None,
                                   unroll=scan_unroll)
             x = nn.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         if return_hidden:
-            return x, jnp.sum(aux)
+            # the training head's dot operands are in the compute dtype
+            return x.astype(dtype), jnp.sum(aux)
         logits = _lm_head(params, cfg, x)
         return logits, jnp.sum(aux)
     finally:
@@ -323,7 +332,8 @@ def decode_step(params, cfg: ModelConfig, token, cache, cur_pos, *,
     The period loop is a ``fori_loop`` carrying the cache and updating it
     in place with dynamic_update_slice — a scan's xs→ys would hold TWO full
     copies of the KV cache live (new + old), doubling decode HBM."""
-    x = nn.embed(params["embed"], token, dtype, scale=cfg.embed_scale)
+    x = nn.embed(params["embed"], token, residual_dtype(cfg, dtype),
+                 scale=cfg.embed_scale)
 
     def period_body(x, slot_params, slot_cache):
         new_caches = []
@@ -353,7 +363,7 @@ def decode_step(params, cfg: ModelConfig, token, cache, cur_pos, *,
                 h = nn.rmsnorm(p["pre_ffn_norm"], x, cfg.norm_eps)
                 x = x + nn.ffn(p["ffn"], h, cfg.ffn_kind, compute_dtype=dtype)
             elif kind == "ssm":
-                h = nn.rmsnorm(p["pre_norm"], x, cfg.norm_eps)
+                h = nn.rmsnorm(p["pre_norm"], x, cfg.norm_eps).astype(dtype)
                 h, nc = ssm.ssm_decode_step(p["ssm"], cfg, h, c,
                                             compute_dtype=dtype)
                 x = x + h

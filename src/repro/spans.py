@@ -28,6 +28,15 @@ program is otherwise the same. Each is placed once, where the work is:
                   unpack copies (``sharded.psum_flat``, the gradient psums
                   of ``pipelined.py``).
 
+One more scope is a tag inside ``trunk`` and not a phase of its own, as
+``head`` is in the loss:
+
+  ``ssd``         Mamba-2's chunked state-space scan (``ssm.ssd_chunked``):
+                  the segment sums and their decays, the in-chunk and
+                  chunk-state einsums and the associative scan across
+                  chunks, in every direction. The block's projections,
+                  convolution and gate around it are not under it.
+
 Forward, recompute and backward are not scopes: JAX writes them into the
 name stack itself. Under ``jax.grad`` the backward's ops carry
 ``transpose(``; ops recomputed under ``jax.checkpoint`` carry
@@ -56,6 +65,7 @@ ACCUMULATE = "accumulate"
 UPDATE = "update"
 GRAD_SYNC = "grad_sync"
 DEVICE_PHASES = (TRUNK, HEAD, ACCUMULATE, UPDATE, GRAD_SYNC)
+SSD = "ssd"
 
 INPUT = "input"
 DISPATCH = "dispatch"

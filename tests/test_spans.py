@@ -12,21 +12,30 @@ from jax.profiler import ProfileData
 
 from repro import configs, engine, spans
 from repro.launch import train
+from repro.models import ssm
 
 PHASE_WORDS = re.compile(r"[/()]")
+METADATA = re.compile(r",? metadata=\{[^}]*\}")
 
 
-def _args(mesh, executor):
+def _without_metadata(hlo_text):
+    """Compiled HLO text less what does not run: the source-location
+    tables between the module's first line and its first computation, and
+    every instruction's ``metadata={...}``."""
+    head, rest = hlo_text.split("\n", 1)
+    return head + METADATA.sub("", rest[re.search(r"^(ENTRY )?%", rest, re.M).start():])
+
+
+def _args(mesh, executor, arch="qwen2-1.5b"):
     return train.parse_args([
-        "--arch", "qwen2-1.5b", "--reduced", "--mini-batch", "4",
+        "--arch", arch, "--reduced", "--mini-batch", "4",
         "--microbatches", "2", "--seq", "32", "--mesh", mesh,
         "--executor", executor, "--calibrate", "off",
         "--remat-policy", "period", "--dtype", "bfloat16"])
 
 
-def _step_hlo(mesh, executor):
-    run = train.setup(configs.get_reduced("qwen2-1.5b"),
-                      _args(mesh, executor))
+def _step_hlo(mesh, executor, arch="qwen2-1.5b"):
+    run = train.setup(configs.get_reduced(arch), _args(mesh, executor, arch))
     step, pipeline = run.build(run.plan)
     batch = next(iter(pipeline.batches(1)))
     lower = getattr(step, "lower", None) or step.__self__.lower_step
@@ -45,6 +54,7 @@ def test_one_device_step_names_every_phase(executor):
     for scope in (spans.TRUNK, spans.HEAD, spans.ACCUMULATE, spans.UPDATE):
         assert any(scope in s for s in stacks), scope
     assert not any(spans.GRAD_SYNC in s for s in stacks)
+    assert not any(spans.SSD in s for s in stacks)  # no scan on this path
     # the directions JAX writes itself: backward, and the period remat's
     # recompute inside it, both under the trunk
     assert any({spans.TRUNK, "transpose"} <= s for s in stacks)
@@ -53,6 +63,26 @@ def test_one_device_step_names_every_phase(executor):
     assert any({spans.HEAD, "transpose"} <= s for s in stacks)
     # JAX's own loop bodies are `while/body`: the trunk is not named so
     assert spans.TRUNK != "body"
+
+
+def test_ssm_step_names_its_scan_and_the_scope_is_metadata_only(monkeypatch):
+    """The SSD scan's ops carry ``ssd`` inside ``trunk``, in the forward,
+    the recompute and the backward; its projections do not. (Constants the
+    compiler hoists out of the loop, such as the causal mask, keep ``ssd``
+    and lose ``trunk``.) Without metadata the compiled step is the same
+    with the scope as without."""
+    text = _step_hlo("1:1", "compiled", "mamba2-780m")
+    stacks = _name_stacks(text)
+    scanned = [s for s in stacks if {spans.TRUNK, spans.SSD} <= s]
+    assert any("transpose" not in s for s in scanned)
+    assert any({"transpose", "rematted_computation"} <= s for s in scanned)
+    assert any("transpose" in s and "rematted_computation" not in s
+               for s in scanned)
+    assert any(spans.TRUNK in s and spans.SSD not in s for s in stacks)
+    monkeypatch.setattr(ssm, "ssd_chunked", ssm.ssd_chunked.__wrapped__)
+    bare = _step_hlo("1:1", "compiled", "mamba2-780m")
+    assert not any(spans.SSD in s for s in _name_stacks(bare))
+    assert _without_metadata(bare) == _without_metadata(text)
 
 
 def test_sharded_step_names_its_gradient_all_reduce():
