@@ -45,7 +45,7 @@ def init_accum(params, dtype):
 
 
 def micro_loss_fn(loss_fn: Callable, normalization: str, n_s, total_valid,
-                  mb, *, defer_scale: bool = False) -> Callable:
+                  mb, *, defer_scale: bool = False, accum=None) -> Callable:
     """The per-micro-batch loss to differentiate.
 
     Exact-mode contract for ``loss_fn``: with ``exact_denom`` set, micro
@@ -62,17 +62,55 @@ def micro_loss_fn(loss_fn: Callable, normalization: str, n_s, total_valid,
     ``defer_scale=True``: the raw micro loss ("paper": micro mean; "exact":
     Σ valid per-sample losses) — the 1/N_Sμ (resp. 1/N_B_valid) scale is
     applied later, fused into the accumulate (see :func:`deferred_scale`).
+
+    ``accum`` is passed on to ``loss_fn`` as ``accum=`` (see
+    :func:`inplace_key`).
     """
+    kw = {} if accum is None else {"accum": accum}
+
     def f(p):
         if normalization == "paper":
-            loss, metrics = loss_fn(p, mb)
+            loss, metrics = loss_fn(p, mb, **kw)
             return (loss, metrics) if defer_scale else (loss / n_s, metrics)
         if normalization != "exact":
             raise ValueError(f"unknown normalization {normalization!r}")
         denom = 1.0 if defer_scale else total_valid
-        loss, metrics = loss_fn(p, mb, exact_denom=denom)
+        loss, metrics = loss_fn(p, mb, exact_denom=denom, **kw)
         return loss, metrics
     return f
+
+
+def inplace_key(loss_fn: Callable, params, accum_dtype, n_s: int,
+                plain_add: bool) -> Optional[str]:
+    """The top-level key of ``params`` whose gradient the backward adds
+    into the accumulator itself, or None.
+
+    A loss that can do so names the key in ``loss_fn.accum_key`` and takes
+    that slice of the accumulator as ``accum=`` (``steps.make_loss_fn``
+    under a remat policy that recomputes per period). It is used where the
+    accumulate is a plain add (no scale deferred into it), the split batch
+    has more than one micro-batch (at N_Sμ = 1 the compiler already folds
+    ``zeros + g``) and the accumulator has the parameters' dtypes (the
+    gradient of the key comes back as the accumulator)."""
+    key = getattr(loss_fn, "accum_key", None)
+    if key is None or not plain_add or n_s < 2:
+        return None
+    if any(jnp.dtype(p.dtype) != jnp.dtype(accum_dtype)
+           for p in jax.tree.leaves(params[key])):
+        return None
+    return key
+
+
+def inplace_share(params, key: Optional[str]) -> float:
+    """The share of the accumulator's bytes that the backward adds in
+    place: those under ``key`` (:func:`inplace_key`), over all of them."""
+    if key is None:
+        return 0.0
+    return _size(params[key]) / _size(params)
+
+
+def _size(tree) -> int:
+    return sum(int(p.size) for p in jax.tree.leaves(tree))
 
 
 def deferred_scale(normalization: str, n_s, total_valid):
@@ -84,12 +122,23 @@ def deferred_scale(normalization: str, n_s, total_valid):
 
 @jax.named_scope(spans.ACCUMULATE)
 def accumulate(acc, grads, *, scale=None, fused: bool = False,
-               interpret: Optional[bool] = None, block: Optional[int] = None):
+               interpret: Optional[bool] = None, block: Optional[int] = None,
+               added: Optional[str] = None):
     """acc ← acc + [scale ·] grads, in the accumulator's dtype.
 
     ``fused=True`` routes through the Pallas kernel
     (``kernels/grad_accum.py``): scaled accumulate with in-place aliasing on
-    the fp32 buffer, so the scaled gradient is never materialized."""
+    the fp32 buffer, so the scaled gradient is never materialized.
+
+    ``added`` names a top-level key whose gradient the backward already
+    added into the accumulator (:func:`inplace_key`): ``grads[added]`` is
+    the new accumulator there and is taken as it is."""
+    if added is not None:
+        acc = accumulate({k: v for k, v in acc.items() if k != added},
+                         {k: v for k, v in grads.items() if k != added},
+                         scale=scale, fused=fused, interpret=interpret,
+                         block=block)
+        return {**acc, added: grads[added]}
     if fused:
         kw = {"interpret": interpret}
         if block is not None:

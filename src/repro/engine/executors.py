@@ -89,7 +89,11 @@ def _scan_accumulate(loss_fn, plan: MBSPlan, fused: bool, params,
     per-sample losses (``exact_denom=1``), gradients/losses/metrics are
     accumulated as plain sums. The caller divides by the GLOBAL valid count
     after the cross-device reduction — the one place the data-parallel
-    denominator is known."""
+    denominator is known.
+
+    Where the loss can (``exec_core.inplace_key``), the backward adds the
+    layer stack's gradient into the accumulator carry itself, and only
+    the other leaves go through ``exec_core.accumulate``."""
     n_s, total_valid = exec_core.denominators(micro_batches)
     norm = "exact" if raw else plan.normalization
     accum0 = exec_core.init_accum(params, plan.accum_dtype)
@@ -98,6 +102,8 @@ def _scan_accumulate(loss_fn, plan: MBSPlan, fused: bool, params,
     else:
         scale = (exec_core.deferred_scale(plan.normalization, n_s, total_valid)
                  if fused else None)
+    key = exec_core.inplace_key(loss_fn, params, plan.accum_dtype, n_s,
+                                plain_add=scale is None)
     mb0 = jax.tree.map(lambda x: x[0], micro_batches)
     metrics0 = exec_core.metrics_zeros(loss_fn, norm, params, mb0)
     metric_div = 1 if raw else n_s
@@ -105,13 +111,15 @@ def _scan_accumulate(loss_fn, plan: MBSPlan, fused: bool, params,
     def micro_step(carry, mb):
         acc, loss_sum, metric_sum = carry
         lfn = exec_core.micro_loss_fn(loss_fn, norm, n_s, total_valid, mb,
-                                      defer_scale=fused or raw)
+                                      defer_scale=fused or raw,
+                                      accum=None if key is None else acc[key])
         grad_fn = jax.value_and_grad(lfn, has_aux=True)
         if plan.remat_micro_step:
             grad_fn = jax.checkpoint(grad_fn)
         (l, metrics), grads = grad_fn(params)
         acc = exec_core.accumulate(acc, grads, scale=scale, fused=fused,
-                                   interpret=interpret, block=block)
+                                   interpret=interpret, block=block,
+                                   added=key)
         metric_sum = jax.tree.map(lambda s, m: s + m / metric_div,
                                   metric_sum, metrics)
         return (acc, loss_sum + l, metric_sum), None
@@ -158,6 +166,14 @@ class _CompiledExecutorBase:
     def _accumulated(self, params, micro_batches):
         return _scan_accumulate(self.loss_fn, self.plan, self.fused, params,
                                 micro_batches, self._interpret, self._block)
+
+    def inplace_accum_share(self, params) -> float:
+        """The share of the accumulator's bytes that the backward adds in
+        place (``exec_core.inplace_key``); static, from the tree and the
+        plan."""
+        return exec_core.inplace_share(params, exec_core.inplace_key(
+            self.loss_fn, params, self.plan.accum_dtype,
+            self.plan.num_micro_batches, plain_add=not self.fused))
 
     def raw_accumulate(self, params, micro_batches):
         """Traceable UN-normalized accumulation over a (local) split batch:

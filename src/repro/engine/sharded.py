@@ -200,6 +200,14 @@ class ShardedExecutor:
     def stage(self, split):
         return jax.device_put(split, self.batch_shardings(split))
 
+    def inplace_accum_share(self, params) -> float:
+        """The inner strategy's share of the accumulator added in place
+        (``CompiledScanExecutor.inplace_accum_share``); 0 where the local
+        half is not its ``raw_accumulate``."""
+        if self.inner is None or not self.defer_sync:
+            return 0.0
+        return self.inner.inplace_accum_share(params)
+
     # -- the local (per-device) halves of the step --------------------------
 
     def _raw_local(self, params, mb):

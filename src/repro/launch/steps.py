@@ -90,12 +90,21 @@ def make_loss_fn(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
     """``remat_policy`` grades activation checkpointing (``models/remat``);
     None keeps the legacy ``remat`` bool mapping (True → "period",
     False → "none"). Pass the *plan's* chosen policy here so the compiled
-    loss matches what the planner admitted."""
+    loss matches what the planner admitted.
+
+    Where the policy recomputes per period (``remat.RECOMPUTES_PERIOD``)
+    and the model is decoder-only, the loss takes ``accum=``, an
+    accumulator shaped like ``params["blocks"]``: the gradient of
+    ``blocks`` then comes back as ``accum`` plus that gradient
+    (``transformer.forward``). Such a loss names that key in
+    ``loss_fn.accum_key``, where the executors look for it."""
     policy = remat_lib.resolve(remat, remat_policy)
 
-    def loss_fn(params, mb, exact_denom=None):
+    def loss_fn(params, mb, exact_denom=None, accum=None):
         sw = mb.get("sample_weight")
         if cfg.is_encdec:
+            if accum is not None:
+                raise ValueError(f"{cfg.name}: enc-dec takes no accum")
             x, aux = encdec.forward(params, cfg, mb["frames"],
                                     mb["tgt_tokens"], dtype=dtype,
                                     remat_policy=policy,
@@ -107,7 +116,7 @@ def make_loss_fn(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
                 vision_embeds=mb.get("vision_embeds"),
                 mrope_positions=mb.get("mrope_positions"),
                 dtype=dtype, remat_policy=policy, scan_unroll=scan_unroll,
-                return_hidden=True)
+                return_hidden=True, accum=accum)
         loss = head_loss(params, cfg, x, mb["labels"], sample_weight=sw,
                          exact_denom=exact_denom)
         if cfg.is_moe:
@@ -124,6 +133,8 @@ def make_loss_fn(cfg: ModelConfig, dtype=jnp.bfloat16, remat: bool = True,
             loss = loss + aux_term
         return loss, {"aux_loss": aux}
 
+    if not cfg.is_encdec and policy in remat_lib.RECOMPUTES_PERIOD:
+        loss_fn.accum_key = "blocks"
     return loss_fn
 
 

@@ -152,7 +152,8 @@ def build_executor(cfg, plan, args, optimizer=None, mesh=None, guard=False,
                                               guard=guard, **kw), opt
 
 
-def make_build(cfg, args, ds, mesh, host_dp, opt, interpret=None):
+def make_build(cfg, args, ds, mesh, host_dp, opt, interpret=None,
+               params=None):
     """``plan -> (step_fn, pipeline)``: one factory for all three runtime
     shapes (host-DP sharded, single-device streaming, GSPMD compiled).
     ``main()`` calls it once for the plain ``Trainer``; the Supervisor
@@ -161,13 +162,17 @@ def make_build(cfg, args, ds, mesh, host_dp, opt, interpret=None):
     geometry) is reconstructed from scratch for the new plan. The GSPMD
     step also carries ``step.lower(params, opt_state, batch)``, the
     lowering of the very jit it dispatches (for ``memory_analysis`` and
-    the compiled HLO)."""
+    the compiled HLO). With ``params`` it prints the executor's
+    ``inplace_accum_share`` for each plan it builds."""
     guard = args.supervise
 
     def build(plan):
         executor, _ = build_executor(cfg, plan, args, optimizer=opt,
                                      mesh=mesh if host_dp else None,
                                      guard=guard, interpret=interpret)
+        if params is not None and hasattr(executor, "inplace_accum_share"):
+            print(f"in-place accumulate share "
+                  f"{executor.inplace_accum_share(params):.3f}", flush=True)
         if host_dp:
             # data-parallel host mesh (engine Layer 6): per-device
             # accumulation of local_micro samples, ONE deferred gradient
@@ -445,7 +450,8 @@ def setup(cfg, args, interpret=None) -> Setup:
         opt_state = opt.init(params)
         state_shardings = None
 
-    build = make_build(cfg, args, ds, mesh, host_dp, opt, interpret=interpret)
+    build = make_build(cfg, args, ds, mesh, host_dp, opt, interpret=interpret,
+                       params=params)
     return Setup(mesh, opt, plan, params, opt_state, state_shardings, build)
 
 

@@ -57,8 +57,9 @@ def set_seq_shard(enabled):
     Measured: big win for dense/hybrid/ssm stacks (gemma2 train: −58%
     collective, −62% compute), a regression for MoE stacks (mixtral: +170%
     collective from dispatch-buffer reshard churn) — so forward() gates it
-    by family."""
-    _SEQ_STATE["enabled"] = enabled
+    by family. Returns the override it replaces."""
+    prev, _SEQ_STATE["enabled"] = _SEQ_STATE["enabled"], enabled
+    return prev
 
 
 def _seq_shard_on() -> bool:

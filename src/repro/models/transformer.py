@@ -180,21 +180,30 @@ def forward(params, cfg: ModelConfig, tokens, *, positions=None,
             vision_embeds=None, mrope_positions=None, dtype=jnp.bfloat16,
             global_window=None, remat: bool = True,
             remat_policy: Optional[str] = None, return_hidden=False,
-            scan_unroll: int = 1):
+            scan_unroll: int = 1, accum=None):
     """Full-sequence forward (training / prefill). tokens: (B, S) int32.
 
     ``remat_policy`` grades activation checkpointing (see ``models/remat``);
     when None the legacy ``remat`` bool maps onto the lattice
     (True → "period", False → "none").
 
+    ``accum`` (a gradient accumulator shaped like ``params["blocks"]``,
+    under a policy of ``remat.RECOMPUTES_PERIOD``) runs the period stack
+    through ``remat.accumulating_scan``: the gradient of ``blocks`` comes
+    back as ``accum`` plus that gradient. None leaves the program as it is.
+
     Returns (logits (B,S,V) fp32, aux_loss scalar)."""
     policy = remat_lib.resolve(remat, remat_policy)
+    if accum is not None and policy not in remat_lib.RECOMPUTES_PERIOD:
+        raise ValueError(f"accum needs a remat policy of "
+                         f"{remat_lib.RECOMPUTES_PERIOD}, got {policy!r}")
     B, S = tokens.shape[:2]
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     # sequence parallelism: measured win for dense/hybrid/ssm, regression
     # for MoE (see nn.set_seq_shard) — gate by family
-    nn.set_seq_shard(False if cfg.is_moe else None)
+    seq_shard = False if cfg.is_moe else None
+    nn.set_seq_shard(seq_shard)
     try:
         with jax.named_scope(spans.TRUNK):
             x = nn.seq_sharded(_embed_inputs(params, cfg, tokens,
@@ -213,11 +222,16 @@ def forward(params, cfg: ModelConfig, tokens, *, positions=None,
 
             period_fn = remat_lib.checkpoint_period(period_fn, policy)
 
-            def scan_body(x, slot_params):
-                return period_fn(x, slot_params)
+            if accum is None:
+                def scan_body(x, slot_params):
+                    return period_fn(x, slot_params)
 
-            x, aux = jax.lax.scan(scan_body, x, params["blocks"],
-                                  unroll=scan_unroll)
+                x, aux = jax.lax.scan(scan_body, x, params["blocks"],
+                                      unroll=scan_unroll)
+            else:
+                x, aux = remat_lib.accumulating_scan(
+                    _with_seq_shard(period_fn, seq_shard), params["blocks"],
+                    accum, x, scan_unroll)
             x = nn.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         if return_hidden:
             # the training head's dot operands are in the compute dtype
@@ -226,6 +240,18 @@ def forward(params, cfg: ModelConfig, tokens, *, positions=None,
         return logits, jnp.sum(aux)
     finally:
         nn.set_seq_shard(None)
+
+
+def _with_seq_shard(fn, enabled):
+    """``fn`` traced under ``nn.set_seq_shard(enabled)`` whenever it is
+    traced: a custom VJP's backward is traced after ``forward`` returned."""
+    def wrapped(*args):
+        prev = nn.set_seq_shard(enabled)
+        try:
+            return fn(*args)
+        finally:
+            nn.set_seq_shard(prev)
+    return wrapped
 
 
 @jax.named_scope(spans.HEAD)
