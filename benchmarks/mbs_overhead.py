@@ -29,7 +29,7 @@ buys batch the cheaper policies cannot.
 ``--mesh-bench`` benchmarks sharded execution (engine Layer 6) and writes
 ``BENCH_mesh.json``: at data-parallel 2/4/8 (forced host devices), the
 deferred-sync ShardedExecutor step time vs the per-micro-sync baseline,
-the all-reduce counts each compiles to on an unrolled scan (1 vs
+the all-reduce operands each compiles to on an unrolled scan (1 vs
 N_Sμ + 1 — the baseline also pays a scalar loss/valid sync), and the
 global batch the mesh-aware planner admits at a fixed per-device budget
 as the data axis grows. N_Sμ is recorded per row: the planner's
@@ -720,9 +720,12 @@ def serve_main(quick: bool = True, out_path: str = "BENCH_serve.json"):
 
 
 def _count_allreduce(jitted, *args) -> int:
-    import re
-    hlo = jitted.lower(*args).compile().as_text()
-    return len(re.findall(r"all-reduce(?:-start)?\(", hlo))
+    """All-reduce operands of the compiled step (XLA's combiner may print
+    the baseline's syncs as one tuple all-reduce)."""
+    from repro.analysis.hlo_checks import collectives
+    return sum(len(c.operand_bytes)
+               for c in collectives(jitted.lower(*args).compile())
+               if c.kind == "all-reduce")
 
 
 def mesh_main(quick: bool = True, out_path: str = "BENCH_mesh.json"):

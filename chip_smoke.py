@@ -278,7 +278,8 @@ def four_chips(cfg, devices):
                            seq=FOUR_CHIP_SEQ))
     executor = timed.step.__self__
     compiled = executor.lower_step(*timed.avals).compile()
-    n_ar = hlo_checks.allreduce_count(compiled)
+    n_ar = sum(c.kind == "all-reduce"
+               for c in hlo_checks.collectives(compiled))
     print(f"  4:1 compiled step: {n_ar} all-reduce per mini-batch "
           "(contract: 1)", flush=True)
     if n_ar != 1:

@@ -29,11 +29,11 @@ from .jaxpr_checks import (check_accum_dtype, check_collectives,  # noqa: F401
                            check_host_callbacks, check_pipeline_collectives,
                            check_pipelined_step, check_remat_policy,
                            check_train_step, count_primitive, iter_eqns)
-from .hlo_checks import (allreduce_count, check_aliasing,  # noqa: F401
+from .hlo_checks import (check_aliasing,  # noqa: F401
                          check_gradient_sync, check_memory_model,
                          check_pipeline_hlo, check_unexpected_ops,
-                         collective_bytes, hlo_text, measured_peak_bytes,
-                         tree_bytes)
+                         collective_bytes, collectives, hlo_text,
+                         measured_peak_bytes, tree_bytes)
 from .lint import (category_for, lint_paths, lint_repo,  # noqa: F401
                    lint_source)
 from .suite import TARGETS, check_bundle, run_suite  # noqa: F401

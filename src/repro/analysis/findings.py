@@ -68,13 +68,14 @@ RULES: Dict[str, Dict[str, str]] = {
                "contract": "compiled peak bytes agree with "
                            "core/memory_model within declared tolerance"},
     "HLO004": {"layer": "hlo",
-               "contract": "compiled collective schedule: one all-reduce "
-                           "per mini-batch (deferred) / >= N_Smu "
-                           "(per-micro baseline)"},
+               "contract": "compiled gradient sync, counted in "
+                           "non-scalar all-reduce operands: one per "
+                           "mini-batch (deferred) / >= N_Smu (per-micro "
+                           "baseline) / no all-reduce (mesh-free)"},
     "HLO005": {"layer": "hlo",
                "contract": "compiled pipelined schedule: exactly two "
-                           "non-scalar all-reduces (staged-grad data "
-                           "psum + shared data-model psum) when "
+                           "non-scalar all-reduce operands (staged-grad "
+                           "data psum + shared data-model psum) when "
                            "deferred, >= N_Smu when per-micro; "
                            "collective-permute count bounded by the "
                            "jaxpr schedule census (XLA may merge "

@@ -1,46 +1,45 @@
-"""Compiled-HLO contract checks (rules HLO001–HLO004).
+"""Compiled-HLO contract checks (rules HLO001–HLO005).
 
 Operates on ``jax.jit(step).lower(*abstract).compile()`` artifacts
 (``executor.lower_step`` exposes these) — ``memory_analysis()`` for the
-byte-level contracts, ``as_text()`` for the op census. This module is
-the single source of truth for HLO text queries: ``launch/dryrun.py``
-re-exports :func:`collective_bytes` from here, and the mesh/flat test
-suites assert their collective/aliasing contracts through this API
-instead of hand-parsing HLO strings.
+byte-level contracts, ``as_text()`` for the collective census. This
+module is the single source of truth for HLO text queries: every
+collective is read by :func:`collectives`; ``launch/dryrun.py``
+re-exports :func:`collective_bytes` from here, and the test suites
+assert their collective contracts through this API instead of
+hand-parsing HLO strings.
 """
 from __future__ import annotations
 
+import math
 import re
-from typing import Any, Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
-from .findings import Finding, SEVERITY_ERROR, SEVERITY_WARNING
+from .findings import Finding, SEVERITY_ERROR
 
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
                 "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
                 "f64": 8, "c64": 8, "c128": 16}
 
-_COLL_RE = re.compile(
-    r"^\s*(?:%?[\w.\-]+ = )?(?P<out>\(?[\w\[\],{}\s/#*]*?\)?)\s*"
-    r"(?P<op>all-gather|all-reduce|reduce-scatter|all-to-all|"
-    r"collective-permute|collective-broadcast)(?:-start|-done)?\(",
-    re.M)
-
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+#: ``%name = <shape or tuple of shapes> <kind>[-start|-done](...``
+#: up to the instruction's ``op_name`` metadata, where it has one
+_COLLECTIVE_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(?P<out>.+?)\s+"
+    r"(?P<kind>all-gather|all-reduce|reduce-scatter|all-to-all|"
+    r"collective-permute|collective-broadcast)(?P<phase>-start|-done)?\("
+    r"(?:.*?op_name=\"(?P<op_name>[^\"]*)\")?", re.M)
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 
 
-def _shape_bytes(type_str: str) -> int:
-    total = 0
-    for dt, dims in _SHAPE_RE.findall(type_str):
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
+class Collective(NamedTuple):
+    """One collective instruction: its kind, the byte size of each operand
+    of its (possibly tuple) output, and its ``op_name`` ("" if none)."""
+    kind: str
+    operand_bytes: Tuple[int, ...]
+    op_name: str
 
 
 def hlo_text(obj) -> str:
@@ -54,25 +53,34 @@ def hlo_text(obj) -> str:
     raise TypeError(f"cannot extract HLO text from {type(obj)!r}")
 
 
-def collective_bytes(obj) -> Dict[str, Dict[str, int]]:
-    """Per-device output bytes + op count of every collective, by kind.
+def collectives(obj) -> List[Collective]:
+    """Every collective instruction in the compiled HLO text, in order.
 
-    ``-start``/``-done`` async halves count once (the ``-done`` arm has no
-    shaped output payload in the regex's capture)."""
-    out: Dict[str, Dict[str, int]] = {}
-    for m in _COLL_RE.finditer(hlo_text(obj)):
-        op = m.group("op")
-        b = _shape_bytes(m.group("out"))
-        d = out.setdefault(op, {"bytes": 0, "count": 0})
-        d["bytes"] += b
-        d["count"] += 1
+    ``/*...*/`` comments (XLA prints ``/*index=5*/`` inside long tuples)
+    are stripped first; an async ``-start``/``-done`` pair counts once,
+    as its ``-start`` (which carries the shape)."""
+    out = []
+    for m in _COLLECTIVE_RE.finditer(_COMMENT_RE.sub("", hlo_text(obj))):
+        if m.group("phase") == "-done":
+            continue
+        sizes = tuple(
+            _DTYPE_BYTES[dt] * math.prod(int(d) for d in dims.split(",") if d)
+            for dt, dims in _SHAPE_RE.findall(m.group("out"))
+            if dt in _DTYPE_BYTES)
+        out.append(Collective(m.group("kind"), sizes,
+                              m.group("op_name") or ""))
     return out
 
 
-def allreduce_count(obj) -> int:
-    """Number of all-reduce launches in the compiled module (async
-    ``all-reduce-start`` counted once, ``-done`` ignored)."""
-    return len(re.findall(r"all-reduce(?:-start)?\(", hlo_text(obj)))
+def collective_bytes(obj) -> Dict[str, Dict[str, int]]:
+    """Per-device output bytes + instruction count of every collective,
+    by kind."""
+    out: Dict[str, Dict[str, int]] = {}
+    for c in collectives(obj):
+        d = out.setdefault(c.kind, {"bytes": 0, "count": 0})
+        d["bytes"] += sum(c.operand_bytes)
+        d["count"] += 1
+    return out
 
 
 def tree_bytes(tree) -> int:
@@ -125,17 +133,14 @@ def check_unexpected_ops(obj, *, expect_gather: bool = False,
     paths DO gather — pass ``expect_gather=True`` there.)"""
     if expect_gather:
         return []
-    census = collective_bytes(obj)
-    out = []
-    for op in ("all-gather",):
-        if op in census:
-            out.append(Finding(
-                "HLO002", SEVERITY_ERROR,
-                f"{census[op]['count']} unexpected {op} op(s) "
-                f"({census[op]['bytes']} bytes) in a replicated-state "
-                "step", location=context,
-                details={"op": op, **census[op]}))
-    return out
+    gather = collective_bytes(obj).get("all-gather")
+    if not gather:
+        return []
+    return [Finding(
+        "HLO002", SEVERITY_ERROR,
+        f"{gather['count']} unexpected all-gather op(s) ({gather['bytes']} "
+        "bytes) in a replicated-state step", location=context,
+        details={"op": "all-gather", **gather})]
 
 
 # ---------------------------------------------------------------------------
@@ -175,21 +180,20 @@ def check_memory_model(compiled, modeled_bytes: Optional[int], *,
 # HLO004 — compiled gradient-sync schedule
 # ---------------------------------------------------------------------------
 
-#: all-reduce payloads at or under this byte count are treated as
+#: all-reduce operands at or under this byte count are treated as
 #: scalar/metric traffic (the grad-norm scalar, XLA-introduced scalar
 #: syncs from sharding propagation), not gradient syncs
 _SCALAR_ALLREDUCE_BYTES = 64
 
 
-def _op_payloads(obj, op: str) -> List[int]:
-    """Per-instruction output payload bytes for one collective kind
-    (async ``-done`` arms skipped — the ``-start`` carries the shape)."""
-    out = []
-    for m in _COLL_RE.finditer(hlo_text(obj)):
-        if m.group("op") != op or m.group(0).rstrip("(").endswith("-done"):
-            continue
-        out.append(_shape_bytes(m.group("out")))
-    return out
+def _allreduce_census(colls: List[Collective]) -> Tuple[int, int]:
+    """(all-reduce operands, non-scalar ones). Gradient syncs are counted
+    by operand, not by instruction: XLA's all-reduce combiner prints N
+    syncs as one tuple all-reduce of N operands, and the chip still moves
+    N gradients."""
+    ops = [b for c in colls if c.kind == "all-reduce"
+           for b in c.operand_bytes]
+    return len(ops), sum(b > _SCALAR_ALLREDUCE_BYTES for b in ops)
 
 
 def check_pipeline_hlo(obj, *, expect: str, n_micro: int,
@@ -197,9 +201,9 @@ def check_pipeline_hlo(obj, *, expect: str, n_micro: int,
                        context: str = "") -> List[Finding]:
     """HLO005 — the compiled pipelined (1F1B) schedule.
 
-    All-reduces are classified by payload: non-scalar ones are gradient
-    syncs (deferred contract: exactly TWO — the stage-local flat data
-    psum and the shared (data, model) psum; per-micro baseline: >=
+    All-reduce operands are classified by payload: non-scalar ones are
+    gradient syncs (deferred contract: exactly TWO — the stage-local flat
+    data psum and the shared (data, model) psum; per-micro baseline: >=
     N_Smu), scalar ones are metric traffic (the grad-norm scalar plus
     whatever scalar syncs XLA's sharding propagation introduces) and
     exempt. The collective-permute count is bounded, not pinned: XLA
@@ -209,27 +213,21 @@ def check_pipeline_hlo(obj, *, expect: str, n_micro: int,
     boundary traffic the executor never issued."""
     if expect not in ("deferred", "per-micro"):
         raise ValueError(f"bad expect {expect!r}")
-    ars = _op_payloads(obj, "all-reduce")
-    big = [b for b in ars if b > _SCALAR_ALLREDUCE_BYTES]
-    perms = len(_op_payloads(obj, "collective-permute"))
-    details = {"nonscalar_allreduces": len(big),
-               "scalar_allreduces": len(ars) - len(big),
-               "collective_permutes": perms,
-               "max_ppermutes": max_ppermutes,
+    colls = collectives(obj)
+    n_ops, grads = _allreduce_census(colls)
+    perms = sum(c.kind == "collective-permute" for c in colls)
+    details = {"nonscalar_operands": grads, "scalar_operands": n_ops - grads,
+               "collective_permutes": perms, "max_ppermutes": max_ppermutes,
                "n_micro": n_micro, "expect": expect}
     out: List[Finding] = []
-    if expect == "deferred" and len(big) != 2:
+    if (grads != 2 if expect == "deferred" else grads < n_micro):
+        want = ("exactly 2 (stage-local data psum + shared data-model psum)"
+                if expect == "deferred" else f">= {n_micro}")
         out.append(Finding(
             "HLO005", SEVERITY_ERROR,
-            f"deferred pipelined step compiled to {len(big)} non-scalar "
-            "all-reduce(s), contract is exactly 2 (stage-local data psum "
-            "+ shared data-model psum)", location=context, details=details))
-    if expect == "per-micro" and len(big) < n_micro:
-        out.append(Finding(
-            "HLO005", SEVERITY_ERROR,
-            f"per-micro pipelined baseline compiled to {len(big)} "
-            f"non-scalar all-reduce(s), expected >= {n_micro}",
-            location=context, details=details))
+            f"{expect} pipelined step compiled to {grads} non-scalar "
+            f"all-reduce operand(s), contract is {want}", location=context,
+            details=details))
     if not (1 <= perms <= max_ppermutes):
         out.append(Finding(
             "HLO005", SEVERITY_ERROR,
@@ -241,31 +239,28 @@ def check_pipeline_hlo(obj, *, expect: str, n_micro: int,
 
 def check_gradient_sync(obj, *, expect: str, n_micro: int,
                         context: str = "") -> List[Finding]:
-    """The PR-5 contract at the HLO level: a deferred-sync sharded step
-    compiles to exactly ONE all-reduce per mini-batch; the per-micro
-    baseline to >= N_Sμ; a mesh-free step to zero. NOTE the compiled
-    module keeps rolled loops rolled — pass an UNROLLED plan (or trust
-    the jaxpr-level JX004, which multiplies scan trip counts) when the
-    micro loop is a scan."""
+    """The PR-5 contract at the HLO level, counted in non-scalar
+    all-reduce operands: a deferred-sync sharded step syncs exactly ONE
+    gradient operand per mini-batch; the per-micro baseline >= N_Sμ
+    (however XLA's combiner groups them into instructions); a mesh-free
+    step has no all-reduce at all. NOTE the compiled module keeps rolled
+    loops rolled — pass an UNROLLED plan (or trust the jaxpr-level
+    JX004, which multiplies scan trip counts) when the micro loop is a
+    scan."""
     if expect not in ("none", "deferred", "per-micro"):
         raise ValueError(f"bad expect {expect!r}")
-    count = allreduce_count(obj)
-    details = {"all_reduce_count": count, "n_micro": n_micro,
-               "expect": expect}
-    if expect == "none" and count != 0:
-        return [Finding("HLO004", SEVERITY_ERROR,
-                        f"{count} all-reduce op(s) in a mesh-free step",
-                        location=context, details=details)]
-    if expect == "deferred" and count != 1:
-        return [Finding(
-            "HLO004", SEVERITY_ERROR,
-            f"deferred-sync step compiled to {count} all-reduce ops, "
-            "contract is exactly 1 per mini-batch",
-            location=context, details=details)]
-    if expect == "per-micro" and count < n_micro:
-        return [Finding(
-            "HLO004", SEVERITY_ERROR,
-            f"per-micro baseline compiled to {count} all-reduce ops, "
-            f"expected >= {n_micro}",
-            location=context, details=details)]
-    return []
+    n_ops, grads = _allreduce_census(collectives(obj))
+    details = {"allreduce_operands": n_ops, "nonscalar_operands": grads,
+               "n_micro": n_micro, "expect": expect}
+    if expect == "none":
+        bad = n_ops and f"{n_ops} all-reduce operand(s) in a mesh-free step"
+    elif expect == "deferred":
+        bad = grads != 1 and (
+            f"deferred-sync step compiled to {grads} non-scalar all-reduce "
+            "operand(s), contract is exactly 1 per mini-batch")
+    else:
+        bad = grads < n_micro and (
+            f"per-micro baseline compiled to {grads} non-scalar all-reduce "
+            f"operand(s), expected >= {n_micro}")
+    return ([Finding("HLO004", SEVERITY_ERROR, bad, location=context,
+                     details=details)] if bad else [])

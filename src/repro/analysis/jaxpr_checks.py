@@ -4,13 +4,14 @@ Operates on the *traced* train step — ``executor.trace_step(...)`` /
 ``jax.make_jaxpr`` over ``ShapeDtypeStruct``s — so every check runs
 without allocating or executing anything (dryrun-style).
 
-Primitive names are the jax 0.4.x ones: ``jax.checkpoint`` traces to
-``remat2``, collectives to ``psum``, host callbacks to
-``debug_callback``/``io_callback``, and a ``shard_map``-wrapped body to a
-``shard_map`` equation whose body jaxpr hangs off ``eqn.params``. A
-``lax.scan`` equation carries ``length``/``num_carry``/``num_consts``, so
-the micro-batch loop is analyzable structurally — no unrolling needed:
-a collective *inside* a scan of length N executes N times.
+Primitive names are those of the installed JAX (0.9): ``jax.jit`` traces
+to ``jit``, ``jax.checkpoint`` to ``remat2``, collectives to ``psum``,
+host callbacks to ``debug_callback``/``io_callback``, and a
+``shard_map``-wrapped body to a ``shard_map`` equation whose body jaxpr
+hangs off ``eqn.params``. A ``lax.scan`` equation carries
+``length``/``num_carry``/``num_consts``, so the micro-batch loop is
+analyzable structurally — no unrolling needed: a collective *inside* a
+scan of length N executes N times.
 """
 from __future__ import annotations
 
@@ -123,7 +124,7 @@ def check_accum_dtype(jaxpr, plan, params) -> List[Finding]:
     """Every micro-gradient accumulator in the traced step carries
     ``plan.accum_dtype``. Accumulators are located structurally: carries
     of the outermost scan(s) whose length is N_Sμ (the micro-batch loop),
-    falling back to accumulator-shaped outputs of per-micro ``pjit``
+    falling back to accumulator-shaped outputs of per-micro ``jit``
     dispatches (the eager streaming pipeline)."""
     expected = jnp.dtype(plan.accum_dtype)
     n_s = int(plan.num_micro_batches)
@@ -147,17 +148,17 @@ def check_accum_dtype(jaxpr, plan, params) -> List[Finding]:
 
     if not candidates:
         # eager streaming: one jitted dispatch per micro-batch, the
-        # accumulator is threaded through pjit outputs instead of a scan
+        # accumulator is threaded through jit outputs instead of a scan
         for eqn, path, _ in iter_eqns(jaxpr):
-            if eqn.primitive.name != "pjit":
+            if eqn.primitive.name != "jit":
                 continue
-            if any(p.startswith("scan[") or p == "pjit" for p in path):
+            if any(p.startswith("scan[") or p == "jit" for p in path):
                 continue  # top-level dispatches only
             for v in eqn.outvars:
                 aval = getattr(v, "aval", None)
                 if aval is not None and _looks_like_accumulator(
                         aval, shapes, bucket_sizes):
-                    candidates.append((aval, _loc(path, "pjit.out")))
+                    candidates.append((aval, _loc(path, "jit.out")))
 
     for aval, loc in candidates:
         if jnp.dtype(aval.dtype) != expected:

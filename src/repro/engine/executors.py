@@ -417,7 +417,7 @@ class StreamingExecutor:
         per-micro jitted dispatches + the update), stitched into a single
         traceable function. Production never compiles this — the pipeline
         stays eager — but it gives ``repro.analysis`` the same step
-        semantics to inspect (each jitted dispatch shows up as a ``pjit``
+        semantics to inspect (each jitted dispatch shows up as a ``jit``
         equation)."""
         def whole(p, o, split):
             n_s = jax.tree.leaves(split)[0].shape[0]

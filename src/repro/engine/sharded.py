@@ -314,7 +314,7 @@ class ShardedExecutor:
         ``repro.analysis`` jaxpr checks (collective census, accumulator
         dtype) consume. For the eager streaming inner the per-micro jitted
         dispatches and the sync+update dispatch are stitched into one
-        traceable function (each shows up as a ``pjit`` equation)."""
+        traceable function (each shows up as a ``jit`` equation)."""
         if self.inner_name != "streaming":
             return jax.make_jaxpr(self.make_train_step())(
                 params, opt_state, micro_batches)

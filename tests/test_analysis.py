@@ -180,6 +180,37 @@ def test_hlo003_fires_on_wild_memory_model():
                                        context="pos") == []
 
 
+# XLA's all-reduce combiner prints the 8-micro-batch per-micro baseline's
+# syncs as ONE tuple all-reduce (the f32[2] loss/metric sync + 8 gradient
+# buckets), with a /*index=5*/ comment inside the tuple, and an async
+# collective as a -start/-done pair
+COMBINED_HLO = """\
+  %all-reduce = (f32[2]{0}, f32[192]{0}, f32[192]{0}, f32[192]{0}, \
+f32[192]{0}, /*index=5*/f32[192]{0}, f32[192]{0}, f32[192]{0}, f32[192]{0}) \
+all-reduce(%f, %f.1, %f.3, %f.5, %f.7, /*index=5*/%f.9, %f.11, %f.13, %f.15), \
+channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_49.50, \
+metadata={op_name="jit(train_step)/shard_map/grad_sync/psum"}
+  %get-tuple-element.8 = f32[192]{0} get-tuple-element(%all-reduce), index=1
+  %cp = bf16[4,8]{1,0} collective-permute-start(%x), source_target_pairs={{0,1}}
+  ROOT %cp.done = bf16[4,8]{1,0} collective-permute-done(%cp)
+"""
+
+
+def test_reader_counts_operands_of_a_combined_allreduce():
+    colls = analysis.collectives(COMBINED_HLO)
+    assert [c.kind for c in colls] == ["all-reduce", "collective-permute"]
+    assert colls[0].operand_bytes == (8,) + (768,) * 8
+    assert colls[0].op_name == "jit(train_step)/shard_map/grad_sync/psum"
+    assert colls[1].operand_bytes == (64,) and colls[1].op_name == ""
+    assert analysis.collective_bytes(COMBINED_HLO)["all-reduce"] == {
+        "bytes": 8 + 8 * 768, "count": 1}
+    # one instruction, eight gradient syncs: the census reads eight
+    assert not analysis.check_gradient_sync(COMBINED_HLO, expect="per-micro",
+                                            n_micro=8)
+    assert "HLO004" in _rules(analysis.check_gradient_sync(
+        COMBINED_HLO, expect="deferred", n_micro=8))
+
+
 def test_hlo004_fires_on_per_micro_schedule():
     mesh = host_mesh(4)
     plan, opt, params, opt_state, split = _setup(mesh=mesh, unroll=4)

@@ -10,7 +10,7 @@ import jax
 import pytest
 from jax.profiler import ProfileData
 
-from repro import configs, engine, spans
+from repro import analysis, configs, engine, spans
 from repro.launch import train
 from repro.models import ssm
 
@@ -87,11 +87,11 @@ def test_ssm_step_names_its_scan_and_the_scope_is_metadata_only(monkeypatch):
 
 def test_sharded_step_names_its_gradient_all_reduce():
     text = _step_hlo("2:1", "compiled")
-    reduces = re.findall(r"all-reduce(?:-start)?\(.*", text)
+    reduces = [c for c in analysis.collectives(text)
+               if c.kind == "all-reduce"]
     assert reduces
-    for line in reduces:
-        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
-        assert spans.GRAD_SYNC in PHASE_WORDS.split(op_name), line
+    for c in reduces:
+        assert spans.GRAD_SYNC in PHASE_WORDS.split(c.op_name), c
 
 
 def _host_spans(trace_dir):

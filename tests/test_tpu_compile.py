@@ -6,7 +6,8 @@ with ``interpret=False``, and the benchmark's MBP steps (qwen2-1.5b at 8
 layers, mamba2-780m at all 48). Interpret mode (what every other test runs)
 cannot see what this compiler refuses: tiling, fast-memory limits and
 programs that do not fit the device. Nothing runs, so these tests say
-nothing about results or times.
+nothing about results or times. The gradient-sync census (HLO004) is also
+read off this compiler's HLO for the tiny test model on a 4:1 mesh.
 
 The memory guards hold two different figures. The qwen2 MBP steps hold
 ``hlo_checks.measured_peak_bytes`` (arguments + outputs + temporaries -
@@ -36,7 +37,9 @@ import numpy as np
 import pytest
 from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
-from repro import configs
+from conftest import (make_sharded_executor, tiny_batch, tiny_loss_fn,
+                      tiny_optimizer, tiny_params)
+from repro import configs, engine
 from repro.analysis import hlo_checks
 from repro.kernels import fused_update, grad_accum
 from repro.launch import train
@@ -351,3 +354,28 @@ def test_mbp_step_accumulates_the_trunk_in_place(topo, one_chip, capsys,
               f"{used / 2 ** 30:.3f} GiB, with the plain accumulate "
               f"{PLAIN_ACCUMULATE_BYTES[arch] / 2 ** 30:.3f} GiB")
     assert used <= PLAIN_ACCUMULATE_BYTES[arch]
+
+
+def test_gradient_census_reads_the_chips_hlo(topo, one_chip):
+    """The HLO004 census on what the chip's compiler prints (layouts such
+    as ``{0:T(256)S(1)}``) for the tiny model on the 4:1 mesh, 8
+    micro-batches unrolled: the deferred step syncs one gradient operand,
+    and the per-micro baseline's 8 come out combined into one tuple
+    all-reduce yet still count as 8, so it fails the deferred census."""
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    opt = tiny_optimizer()
+    plan = engine.plan_mbs(64, num_microbatches=8, mesh=mesh, unroll=8)
+    params = tiny_params()
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+        (params, opt.init(params), plan.device_split(tiny_batch(64))))
+    for defer, expect in ((True, "deferred"), (False, "per-micro")):
+        ex = make_sharded_executor("compiled", tiny_loss_fn, opt, plan, mesh,
+                                   donate=False, defer_sync=defer)
+        compiled = jax.jit(ex.make_train_step()).lower(*shapes).compile()
+        assert sum(c.kind == "all-reduce"
+                   for c in hlo_checks.collectives(compiled)) == 1
+        assert not hlo_checks.check_gradient_sync(compiled, expect=expect,
+                                                  n_micro=8)
+    assert hlo_checks.check_gradient_sync(compiled, expect="deferred",
+                                          n_micro=8)
