@@ -2,8 +2,9 @@
 
 The SSD computation is implemented in the *chunked* (block) form: within a
 chunk all work is dense matmuls (MXU-friendly — this is the TPU adaptation of
-the paper's GPU scan), and a short ``lax.scan`` carries the (H, P, N) state
-across chunks. Decode is the O(1) recurrent update.
+the paper's GPU scan), and one masked (chunks x chunks) decay contraction
+passes the (H, P, N) state from chunk to chunk. Decode is the O(1)
+recurrent update.
 
 Shapes: d_inner = expand*d_model, P = head_dim, H = d_inner/P heads,
 N = ssm_state, single B/C group (G=1) as in mamba2-780m.
@@ -108,24 +109,33 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None):
     # chunk summary state: S_c = sum_j exp(cum_last - cum_j) B_j (x_j dt_j)^T
     decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # (B,nc,Q,H)
     states = jnp.einsum("bcjn,bcjh,bcjhp->bchpn", Bc, decay_to_end, xdt)
-    chunk_decay = jnp.exp(cum[:, :, -1, :])  # (B,nc,H) total chunk decay
-
-    # carry state across chunks with an associative scan (log-depth, no
-    # while loop — keeps the MXU busy and the HLO cost-analyzable)
-    s0 = (jnp.zeros((Bsz, H, P, N), f32) if init_state is None
-          else init_state.astype(f32))
-    dec4 = chunk_decay[..., None, None]  # (B,nc,H,1,1)
-
-    def combine(l, r):
-        (dl, sl), (dr, sr) = l, r
-        return dl * dr, sl * dr + sr
-
-    _, s_end = jax.lax.associative_scan(combine, (dec4, states), axis=1)
-    # state entering chunk c = decayed s0 + inclusive-scan result of chunk c-1
-    cumdec = jnp.cumprod(dec4, axis=1)
-    s_end = s_end + cumdec * s0[:, None]
-    s_in = jnp.concatenate([s0[:, None], s_end[:, :-1]], axis=1)  # (B,nc,H,P,N)
-    final = s_end[:, -1]
+    # carry state across chunks in closed form, as the Mamba-2 reference's
+    # ssd_minimal does. With a_c the chunk totals cum[:, c, -1], the state
+    # entering chunk z is
+    #   s_in[z] = sum_{c<z} exp(a_{c+1} + ... + a_{z-1}) states[c]
+    #             + exp(a_0 + ... + a_{z-1}) s0,
+    # and the final state is the same sum at z = nc. decay[z, j] holds each
+    # exponent as a segment sum of its own over the padded totals
+    # (j = 0 is s0, j = c + 1 is states[c]), never as a difference of two
+    # running sums: those reach about -3e3 over 2,048 steps and would lose
+    # the exponent's low bits. Masked before exp, as L is. Each chunk state
+    # is read once and each entering state written once. The contractions
+    # are tiny (nc^2 H P N) and run at HIGHEST, so the carried state keeps
+    # fp32 accuracy and never makes a bf16 pass on the MXU.
+    tot = jnp.pad(cum[:, :, -1, :], ((0, 0), (1, 0), (0, 0)))  # (B,nc+1,H)
+    below = jnp.tril(jnp.ones((nc + 1, nc + 1), bool), -1)[None, :, :, None]
+    seg_c = jnp.cumsum(jnp.where(below, tot[:, :, None, :], 0.0), axis=1)
+    on_or_below = jnp.tril(jnp.ones((nc + 1, nc + 1), bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(on_or_below, seg_c, -jnp.inf))  # (B,z,j,H)
+    hi = jax.lax.Precision.HIGHEST
+    s_in = jnp.einsum("bzch,bchpn->bzhpn", decay[:, :nc, 1:], states,
+                      precision=hi)  # (B,nc,H,P,N)
+    final = jnp.einsum("bch,bchpn->bhpn", decay[:, nc, 1:], states,
+                       precision=hi)
+    if init_state is not None:
+        s0 = init_state.astype(f32)
+        s_in = s_in + decay[:, :nc, 0, :, None, None] * s0[:, None]
+        final = final + decay[:, nc, 0, :, None, None] * s0
     # inter-chunk contribution: y_off[i] = exp(cum_i) * C_i . state_in
     y_off = jnp.einsum("bcin,bcih,bchpn->bcihp", Cc, jnp.exp(cum), s_in)
     y = (y_diag + y_off).reshape(Bsz, S, H, P)[:, :S0]

@@ -33,9 +33,10 @@ One more scope is a tag inside ``trunk`` and not a phase of its own, as
 
   ``ssd``         Mamba-2's chunked state-space scan (``ssm.ssd_chunked``):
                   the segment sums and their decays, the in-chunk and
-                  chunk-state einsums and the associative scan across
-                  chunks, in every direction. The block's projections,
-                  convolution and gate around it are not under it.
+                  chunk-state einsums and the decay contraction that
+                  passes the state across chunks, in every direction.
+                  The block's projections, convolution and gate around
+                  it are not under it.
 
 Forward, recompute and backward are not scopes: JAX writes them into the
 name stack itself. Under ``jax.grad`` the backward's ops carry

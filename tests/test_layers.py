@@ -92,6 +92,104 @@ def test_ssd_matches_sequential_recurrence():
     np.testing.assert_allclose(np.asarray(final), h, atol=1e-4)
 
 
+def _recurrent_ssd(x, dt, A, Bm, Cm, init_state):
+    """The SSD one step at a time, in float32: h_t = exp(dt_t A) h_{t-1} +
+    dt_t B_t x_t^T, y_t = C_t . h_t. Returns (y, final state)."""
+    def step(h, t):
+        xt, dtt, bt, ct = t
+        h = (h * jnp.exp(dtt * A)[..., None, None]
+             + jnp.einsum("bn,bhp->bhpn", bt, xt * dtt[..., None]))
+        return h, jnp.einsum("bn,bhpn->bhp", ct, h)
+    seq = [jnp.moveaxis(v, 1, 0) for v in (x, dt, Bm, Cm)]
+    final, y = jax.lax.scan(step, init_state, seq)
+    return jnp.moveaxis(y, 0, 1), final
+
+
+def _ssd_with_state_loss(ssd, w, w_final):
+    def loss(x, dt, A, Bm, Cm, s0):
+        y, final = ssd(x, dt, A, Bm, Cm, s0)
+        return jnp.sum(y * w) + jnp.sum(final * w_final), (y, final)
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)),
+                                      has_aux=True))
+
+
+def _rel_gap(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+@pytest.mark.parametrize("init", ["zero", "given"])
+@pytest.mark.parametrize("S,chunk", [(8, 8), (7, 4), (61, 4)],
+                         ids=["nc1", "nc2-ragged", "nc16-ragged"])
+def test_ssd_state_passing_matches_recurrence(S, chunk, init):
+    """The state passed between chunks (one masked chunks x chunks decay
+    contraction) at 1, 2 and 16 chunks, with a ragged tail where S is no
+    multiple of the chunk, from a zero and from a given initial state:
+    y, the final state and the gradients of both with respect to x, dt, A,
+    B, C and the initial state agree with the step-by-step recurrence.
+    Both sides are float32 and differ in summation order only: the gaps
+    read at most 2e-6 against 1e-4 here; a wrong decay or a lost chunk
+    gives gaps of order 1."""
+    ks = jax.random.split(jax.random.PRNGKey(S), 8)
+    B, H, P, N = 2, 3, 4, 5
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = 0.5 * jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(0.5 * jax.random.normal(ks[2], (H,)))
+    Bm = jax.random.normal(ks[3], (B, S, N))
+    Cm = jax.random.normal(ks[4], (B, S, N))
+    s0 = (jnp.zeros((B, H, P, N)) if init == "zero"
+          else jax.random.normal(ks[5], (B, H, P, N)))
+    w = jax.random.normal(ks[6], (B, S, H, P))
+    w_final = jax.random.normal(ks[7], (B, H, P, N))
+    chunked = (lambda *a: ssm.ssd_chunked(*a[:5], chunk, a[5])
+               if init == "given" else ssm.ssd_chunked(*a[:5], chunk))
+    (_, got), grads = _ssd_with_state_loss(chunked, w, w_final)(
+        x, dt, A, Bm, Cm, s0)
+    (_, ref), grads_ref = _ssd_with_state_loss(_recurrent_ssd, w, w_final)(
+        x, dt, A, Bm, Cm, s0)
+    assert got[0].shape == (B, S, H, P) and got[1].shape == (B, H, P, N)
+    for name, a, b in zip(("y", "final"), got, ref):
+        assert _rel_gap(a, b) < 1e-4, name
+    names = ("x", "dt", "A", "B", "C", "init_state")
+    for name, g, g_ref in zip(names, grads, grads_ref):
+        if name == "init_state" and init == "zero":
+            continue  # the chunked form was not handed the state
+        assert _rel_gap(g, g_ref) < 1e-4, name
+
+
+@pytest.mark.parametrize("strong_chunks", [16, 12])
+def test_ssd_at_the_strongest_initial_decay_is_finite(strong_chunks):
+    """A = -16 and dt = 0.1, the strongest decay ``ssm_init`` draws, over
+    16 chunks of 256 (at 12, the last 4 chunks take the weakest dt, 1e-4):
+    every decay between chunks of the strong part underflows to 0, and in
+    a chunk the log-decays above the diagonal reach +408, whose exp
+    overflows unless masked first. Every gradient, the initial state's
+    included, is finite, and y and the final state match the recurrence.
+    At 12 the running log-decay reaches -4,915 while each weak chunk adds
+    only -0.41: an exponent taken as the difference of two running sums
+    loses about 5e-4 there (the final state's gap then reads 2.7e-4), the
+    segment sum keeps it (gaps at most 6e-6 on both counts), against 5e-5
+    here."""
+    ks = jax.random.split(jax.random.PRNGKey(16), 7)
+    B, S, H, P, N = 1, 16 * 256, 2, 4, 3
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    strong = jnp.arange(S)[None, :, None] < strong_chunks * 256
+    dt = jnp.where(strong, 0.1, 1e-4) * jnp.ones((B, S, H))
+    A = jnp.full((H,), -16.0)
+    Bm = jax.random.normal(ks[1], (B, S, N))
+    Cm = jax.random.normal(ks[2], (B, S, N))
+    s0 = jax.random.normal(ks[3], (B, H, P, N))
+    w = jax.random.normal(ks[4], (B, S, H, P))
+    w_final = jax.random.normal(ks[5], (B, H, P, N))
+    (_, (y, final)), grads = _ssd_with_state_loss(
+        lambda *a: ssm.ssd_chunked(*a[:5], 256, a[5]), w, w_final)(
+        x, dt, A, Bm, Cm, s0)
+    y_ref, final_ref = jax.jit(_recurrent_ssd)(x, dt, A, Bm, Cm, s0)
+    for name, g in zip(("x", "dt", "A", "B", "C", "init_state"), grads):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+    assert _rel_gap(final, final_ref) < 5e-5
+    assert _rel_gap(y, y_ref) < 5e-5
+
+
 def _quadratic_ssd(x, dt, A, Bm, Cm):
     """The SSD's quadratic ("dual") form over the whole sequence, as in the
     benchmark's float32 reference: y_i = sum_{j<=i} (C_i . B_j)

@@ -2,8 +2,9 @@
 
 The kernels of the training path and the flat executor's whole train step
 are compiled by the chip's own compiler at qwen2-1.5b's published widths,
-with ``interpret=False``, and the benchmark's MBP steps (qwen2-1.5b at 8
-layers, mamba2-780m at all 48). Interpret mode (what every other test runs)
+with ``interpret=False``, the benchmark's MBP steps (qwen2-1.5b at 8
+layers, mamba2-780m at all 48) and one mamba2-780m layer's SSD scan, whose
+bytes accessed are bounded. Interpret mode (what every other test runs)
 cannot see what this compiler refuses: tiling, fast-memory limits and
 programs that do not fit the device. Nothing runs, so these tests say
 nothing about results or times. The gradient-sync census (HLO004) is also
@@ -43,7 +44,7 @@ from repro import configs, engine
 from repro.analysis import hlo_checks
 from repro.kernels import fused_update, grad_accum
 from repro.launch import train
-from repro.models import transformer
+from repro.models import ssm, transformer
 
 V5E_HBM = 16 * 1024 ** 3
 # ``memory_stats()["bytes_limit"]`` of one TPU v5 lite chip, read on the
@@ -285,6 +286,49 @@ def test_mamba2_mbp_step_fits(topo, one_chip, capsys):
               f"{mem.peak_memory_in_bytes / gib:.3f} GiB")
     assert mem.argument_size_in_bytes < mem.peak_memory_in_bytes
     assert mem.peak_memory_in_bytes < V5E_BYTES_LIMIT
+
+
+# the compiler's peak of the mamba2 MBP step for v5e while the SSD scan
+# passed its chunk states through an associative scan
+MAMBA2_MBP_PEAK_BYTES = 12_168_251_904
+
+
+def test_ssd_scan_bytes_for_v5e(topo, one_chip, capsys):
+    """One mamba2-780m layer's ``ssd_chunked`` (seq 2048, 48 heads of 64,
+    state 128, chunks of 256; x, B and C in bf16 as the block hands them
+    over), forward and backward as the gradient of ``sum(y)``. The chunk
+    states cross the chunks in one masked chunks x chunks contraction,
+    which reads each state once: the compiler's ``bytes accessed`` stays
+    at most 6.0e8, where the associative scan's log-depth tree of pads,
+    slices and (P, N) reductions read 1.03e9. Passing the state so takes
+    no more memory: the mamba2 MBP step's peak is no higher than it was
+    with the scan."""
+    cfg = _mamba2()
+    H, P, N = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state
+    S = 2048
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, dt, A, Bm, Cm):
+        y, _ = ssm.ssd_chunked(x, dt, A, Bm, Cm, cfg.ssm_chunk)
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=tuple(range(5))),
+        spec((1, S, H, P), jnp.bfloat16), spec((1, S, H), jnp.float32),
+        spec((H,), jnp.float32), spec((1, S, N), jnp.bfloat16),
+        spec((1, S, N), jnp.bfloat16))
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    peak = _compile_mbp_step(topo, one_chip, cfg, "mamba2-780m", 2048,
+                             16).memory_analysis().peak_memory_in_bytes
+    with capsys.disabled():
+        print(f"\nmamba2-780m ssd_chunked forward + backward for v5e: "
+              f"{cost['bytes accessed']:.4g} bytes accessed, "
+              f"{cost['flops']:.4g} flops; MBP step peak {peak} B")
+    assert cost["bytes accessed"] <= 6.0e8
+    assert peak <= MAMBA2_MBP_PEAK_BYTES
 
 
 # arguments + temporaries of the two MBP steps compiled for v5e while the
